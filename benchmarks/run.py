@@ -29,6 +29,7 @@ import time
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "out")
 
@@ -77,6 +78,7 @@ def main(argv=None) -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
     smoke = args.smoke or args.quick
+    enable_compile_cache()
 
     # explicit global seeding: sections use their own default_rng(0)
     # streams, but anything reaching numpy/python global state is pinned too

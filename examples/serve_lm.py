@@ -9,6 +9,9 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, RunConfig
 from repro.serve.engine import ServeEngine
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 cfg = ModelConfig(name="serve-demo", family="dense", n_layers=4, d_model=256,
                   n_heads=8, n_kv_heads=4, d_ff=768, vocab=4096)

@@ -11,6 +11,9 @@ import numpy as np
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
+from repro.launch.compile_cache import enable_compile_cache
+
+enable_compile_cache()
 
 n, T, W = 1 << 15, 32, 512
 x = jnp.asarray(np.cumsum(np.random.default_rng(0).uniform(-0.01, 0.01, n)),

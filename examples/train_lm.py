@@ -9,9 +9,13 @@ Run:  PYTHONPATH=src python examples/train_lm.py --steps 200
       PYTHONPATH=src python examples/train_lm.py --small --steps 30
 """
 import argparse
+import os
 
 from repro.configs.base import ModelConfig, RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.train.loop import LoopConfig, train
+
+CKPT_DIR = os.path.join(os.path.dirname(__file__), "out", "train_lm_ckpt")
 
 
 def model_100m() -> ModelConfig:
@@ -32,8 +36,9 @@ def main():
     ap.add_argument("--small", action="store_true")
     ap.add_argument("--batch", type=int, default=0)
     ap.add_argument("--seq", type=int, default=0)
-    ap.add_argument("--ckpt", default="/tmp/repro_train_lm")
+    ap.add_argument("--ckpt", default=CKPT_DIR)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = model_small() if args.small else model_100m()
     rc = RunConfig(

@@ -27,8 +27,6 @@ inside manual subgroups, see train/step.py); the equivalent manual-'pod'
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -39,24 +37,6 @@ from repro.obs import instrument as obs
 F32 = jnp.float32
 BLOCK = 32                 # values per scale block (= one bitplane group)
 MIN_COMPRESS_SIZE = 4096   # smaller leaves go raw (scale overhead dominates)
-
-
-def shard_map(f, *, mesh, axis_names, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across the API drift.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=, check_vma=)``; 0.4.x
-    only has ``jax.experimental.shard_map.shard_map`` whose ``auto`` set is
-    the complement of ``axis_names`` and whose replication check is spelled
-    ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, axis_names=frozenset(axis_names),
-                             in_specs=in_specs, out_specs=out_specs,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
 
 
 def _quant_lastdim(x: jax.Array, bits: int):

@@ -61,14 +61,14 @@ class Rules:
             if not self.fsdp:
                 return None
             return "data" if "data" in names and dim % self.axis_size("data") == 0 else None
+        model = ("model" if "model" in names
+                 and dim % self.axis_size("model") == 0 else None)
         if logical == "seq":
-            if not self.seq_shard:
-                return None
-            return "model" if dim % self.axis_size("model") == 0 else None
+            return model if self.seq_shard else None
         if logical == "vocab" and not self.shard_vocab:
             return None
         if logical in ("heads", "ff", "vocab", "cache_seq", "tp"):
-            return "model" if dim % self.axis_size("model") == 0 else None
+            return model
         if logical == "experts":
             return None
         raise KeyError(f"unknown logical axis {logical!r}")
@@ -171,9 +171,8 @@ def tp_out_proj(h: jax.Array, w: jax.Array) -> Optional[jax.Array]:
             out = jax.lax.psum(partial, "model")
         return out.astype(hl.dtype)
 
-    from repro.distributed.collectives import shard_map
     out_spec = P(None, "model", None) if scatter else P(None, None, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, axis_names=frozenset({"model"}),
         in_specs=(P(None, None, "model"), P("model", None)),
         out_specs=out_spec, check_vma=False,

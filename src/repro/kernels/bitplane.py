@@ -1,18 +1,21 @@
 """Pallas TPU kernels: delta + bitplane pack / unpack (paper §2.5 + §2.4).
 
 The FPGA compressor's loop-carried delta chain and bit-serial packing are
-re-expressed for the TPU VPU (see DESIGN.md §2):
+re-expressed for the TPU VPU:
 
 * the delta becomes a shifted lane-wise subtract,
 * variable-length packing becomes a 32x32 bitplane transpose keeping only the
-  ``bits`` low planes (shift/or network, fully vectorized),
+  ``bits`` low planes.  The tile is transposed so that each group of 32
+  lanes becomes 32 sublanes; a plane word is then a signed sum of disjoint
+  bits over those sublanes.  No reshape splits the lane dimension and no
+  reduction runs over unsigned integers (the TPU compiler supports neither),
 * decode reconstructs with a log-depth lane prefix sum (the cumulative sum is
   the inverse of the delta chain).
 
 Tiling: codes are processed in (BM, BLOCK) VMEM tiles, BLOCK a multiple of
-32 lanes x groups; packed planes live in (BM, BLOCK//32*bits) tiles.  All
-dims are multiples of (8, 128) for f32/i32 VMEM tile alignment when
-BLOCK >= 128 and bits*BLOCK//32 >= 128 (asserted in ops.py).
+32 lanes x groups; packed planes live in (BM, BLOCK//32*bits) tiles.  The
+kernels work in int32; the uint32 plane words are a free bitcast outside.
+BM = 128 makes the in-kernel transposes whole (128, 128) tiles.
 """
 from __future__ import annotations
 
@@ -23,7 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 GROUP = 32
-DEF_BM = 8  # sublane tile
+DEF_BM = 128  # rows per tile (smaller arrays take one tile)
 
 
 def _delta_lanes(v: jax.Array) -> jax.Array:
@@ -45,33 +48,32 @@ def _prefix_sum_lanes(v: jax.Array) -> jax.Array:
 
 def _pack_kernel(q_ref, out_ref, *, bits: int, block: int):
     v = q_ref[...]                                    # (BM, BLOCK) int32
-    d = _delta_lanes(v).astype(jnp.uint32)
-    mask = jnp.uint32((1 << bits) - 1) if bits < 32 else jnp.uint32(0xFFFFFFFF)
-    d = d & mask
     bm = v.shape[0]
-    g = d.reshape(bm, block // GROUP, GROUP)
-    w = jnp.uint32(1) << jnp.arange(GROUP, dtype=jnp.uint32)
-    planes = []
-    for j in range(bits):                             # static unroll
-        bit_j = (g >> jnp.uint32(j)) & jnp.uint32(1)
-        planes.append(jnp.sum(bit_j * w, axis=-1, dtype=jnp.uint32))
-    out = jnp.stack(planes, axis=-1)                  # (BM, G, bits)
-    out_ref[...] = out.reshape(bm, -1).astype(jnp.uint32)
+    d = _delta_lanes(v)
+    if bits < 32:
+        d = d & ((1 << bits) - 1)
+    # groups of 32 lanes become 32 sublanes of a (G, 32, BM) stack; the
+    # plane words are signed sums of disjoint bits (no unsigned reduction)
+    g = d.T.reshape(block // GROUP, GROUP, bm)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, GROUP, 1), 1)
+    planes = [jnp.sum(((g >> j) & 1) << lane, axis=1, keepdims=True)
+              for j in range(bits)]                   # static unroll
+    out = jnp.concatenate(planes, axis=1)             # (G, bits, BM)
+    out_ref[...] = out.reshape(-1, bm).T              # (BM, G*bits)
 
 
 def _unpack_kernel(p_ref, out_ref, *, bits: int, block: int):
-    planes = p_ref[...].astype(jnp.uint32)            # (BM, G*bits)
-    bm = planes.shape[0]
-    g = planes.reshape(bm, block // GROUP, bits)
-    vals = jnp.zeros((bm, block // GROUP, GROUP), dtype=jnp.uint32)
-    i = jnp.arange(GROUP, dtype=jnp.uint32)
+    p = p_ref[...]                                    # (BM, G*bits) int32
+    bm = p.shape[0]
+    g = p.T.reshape(block // GROUP, bits, bm)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, GROUP, 1), 1)
+    vals = jnp.zeros((block // GROUP, GROUP, bm), jnp.int32)
     for j in range(bits):                             # static unroll
-        bit_ij = (g[:, :, j][:, :, None] >> i) & jnp.uint32(1)
-        vals = vals | (bit_ij << jnp.uint32(j))
+        vals = vals | (((g[:, j:j + 1, :] >> lane) & 1) << j)
     if bits < 32:
-        h = jnp.uint32(1 << (bits - 1))
+        h = 1 << (bits - 1)
         vals = (vals ^ h) - h                         # sign extend
-    d = vals.astype(jnp.int32).reshape(bm, block)
+    d = vals.reshape(block, bm).T                     # (BM, BLOCK)
     out_ref[...] = _prefix_sum_lanes(d)
 
 
@@ -80,16 +82,18 @@ def pack(q: jax.Array, *, bits: int, block: int, bm: int = DEF_BM,
          interpret: bool = False) -> jax.Array:
     """int32 codes [N, block] -> packed planes uint32 [N, block//32*bits]."""
     n = q.shape[0]
+    bm = min(bm, n)
     assert q.shape == (n, block) and n % bm == 0, (q.shape, bm)
     pw = block // GROUP * bits
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_pack_kernel, bits=bits, block=block),
         grid=(n // bm,),
         in_specs=[pl.BlockSpec((bm, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, pw), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, pw), jnp.uint32),
+        out_shape=jax.ShapeDtypeStruct((n, pw), jnp.int32),
         interpret=interpret,
     )(q)
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "bm", "interpret"))
@@ -97,8 +101,10 @@ def unpack(planes: jax.Array, *, bits: int, block: int, bm: int = DEF_BM,
            interpret: bool = False) -> jax.Array:
     """Packed planes uint32 [N, block//32*bits] -> int32 codes [N, block]."""
     n = planes.shape[0]
+    bm = min(bm, n)
     pw = block // GROUP * bits
     assert planes.shape == (n, pw) and n % bm == 0, (planes.shape, pw, bm)
+    planes = jax.lax.bitcast_convert_type(planes, jnp.int32)
     return pl.pallas_call(
         functools.partial(_unpack_kernel, bits=bits, block=block),
         grid=(n // bm,),

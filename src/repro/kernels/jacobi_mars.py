@@ -28,10 +28,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams (<=0.4.x) to CompilerParams; accept both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    getattr(pltpu, "TPUCompilerParams")
-
 
 def _kernel(x_ref, y_ref, carry_ref, *, t_steps: int, width: int):
     c = pl.program_id(0)
@@ -71,7 +67,7 @@ def jacobi_chunked(x: jax.Array, *, t_steps: int, width: int = 512,
         out_specs=pl.BlockSpec((1, width), lambda c: (0, c)),
         out_shape=jax.ShapeDtypeStruct((1, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((t_steps, 2), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2)

@@ -1,4 +1,4 @@
-"""Pallas TPU kernels: packed KV-cache block quantization (DESIGN.md §3.2).
+"""Pallas TPU kernels: packed KV-cache block quantization.
 
 KV blocks (positions x head_dim) are the serving-side MARS: atomic (a decode
 step reads whole blocks), irredundant (each block stored once), contiguous.
@@ -20,20 +20,26 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEF_BM = 8
+DEF_BM = 128  # rows per tile (smaller arrays take one tile)
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, bits: int):
     x = x_ref[...].astype(jnp.float32)                  # (BM, D)
     qmax = float(2 ** (bits - 1) - 1)
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
+    # multiply by the constant 1/qmax: a division by a constant is
+    # rewritten differently by each compiler, which moves the scale by
+    # an ulp and flips codes at rounding ties
+    scale = jnp.where(amax > 0, amax * (1.0 / qmax), 1.0)
     q = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
     if bits == 8:
         q_ref[...] = q.astype(jnp.int8)
-    else:  # int4: lo nibble = even column; paired reshape stays contiguous
-        pairs = (q & 0xF).reshape(q.shape[0], -1, 2)
-        q_ref[...] = (pairs[:, :, 0] | (pairs[:, :, 1] << 4)).astype(jnp.int8)
+    else:  # int4: lo nibble = even column, paired on sublanes (a reshape
+        # that split the lane dim would not compile for the TPU)
+        t = (q & 0xF).T                                 # (D, BM)
+        pairs = t.reshape(t.shape[0] // 2, 2, t.shape[1])
+        packed = pairs[:, 0, :] | (pairs[:, 1, :] << 4)
+        q_ref[...] = packed.T.astype(jnp.int8)
     s_ref[...] = scale
 
 
@@ -55,6 +61,7 @@ def kv_quant(x: jax.Array, *, bits: int = 8, bm: int = DEF_BM,
              interpret: bool = False):
     """[rows, d] float -> (codes int8, scales f32 [rows, 1])."""
     rows, d = x.shape
+    bm = min(bm, rows)
     assert rows % bm == 0 and (bits == 8 or d % 2 == 0)
     cd = d if bits == 8 else d // 2
     return pl.pallas_call(
@@ -78,6 +85,7 @@ def kv_dequant(codes: jax.Array, scales: jax.Array, *, bits: int = 8,
                bm: int = DEF_BM, interpret: bool = False) -> jax.Array:
     rows, cd = codes.shape
     d = cd if bits == 8 else cd * 2
+    bm = min(bm, rows)
     assert rows % bm == 0
     return pl.pallas_call(
         functools.partial(_dequant_kernel, bits=bits),

@@ -50,7 +50,10 @@ def kv_quant_ref(x: jax.Array, bits: int = 8):
     x = x.astype(jnp.float32)
     qmax = float(2 ** (bits - 1) - 1)
     amax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax / qmax, 1.0)
+    # multiply by the constant 1/qmax: a division by a constant is
+    # rewritten differently by each compiler, which moves the scale by
+    # an ulp and flips codes at rounding ties
+    scale = jnp.where(amax > 0, amax * (1.0 / qmax), 1.0)
     q = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int32)
     if bits == 8:
         return q.astype(jnp.int8), scale

@@ -26,6 +26,9 @@ import sys
 import time
 import traceback
 
+#: the chip the dry-run's roofline terms are computed for
+TARGET_KIND = "TPU v5 lite"
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "benchmarks", "out", "dryrun")
 
@@ -142,11 +145,14 @@ def run_cell(arch: str, shape: str, mesh_kind: str, overrides=None) -> dict:
             rec["bytes_per_device"] - interior, 0.0) + kio
 
         rec["model_flops"] = roofline.model_flops_for(cfg, rc)
+        rec["target_kind"] = TARGET_KIND
         rl = roofline.analyze(rec["flops_per_device"], rec["bytes_per_device"],
-                              rec["collectives"], chips, rec["model_flops"])
+                              rec["collectives"], chips, rec["model_flops"],
+                              device_kind=TARGET_KIND)
         rlk = roofline.analyze(rec["flops_per_device"],
                                rec["bytes_per_device_kernelized"],
-                               rec["collectives"], chips, rec["model_flops"])
+                               rec["collectives"], chips, rec["model_flops"],
+                               device_kind=TARGET_KIND)
         rec["roofline"] = {
             "compute_s": rl.compute_s, "memory_s": rl.memory_s,
             "memory_s_kernelized": rlk.memory_s,

@@ -1,24 +1,34 @@
-"""Production mesh construction (function, never module-level state)."""
+"""Mesh construction (functions, never module-level state).
+
+Every mesh is built with ``Auto`` axes: the models place activations with
+``with_sharding_constraint`` on logical specs (distributed/sharding.py),
+which JAX accepts only on ``Auto`` axes.  ``jax.make_mesh`` defaults to
+``Explicit`` axes, so nothing should call it except through ``make_mesh``.
+"""
 from __future__ import annotations
 
 import os
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto(n: int):
+    return (AxisType.Auto,) * n
+
+
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    axis_names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), axis_names,
+                         axis_types=_auto(len(axis_names)), devices=devices)
 
 
 def abstract_mesh(axis_sizes, axis_names):
-    """``jax.sharding.AbstractMesh`` across the API drift.
-
-    Newer jax takes ``AbstractMesh(axis_sizes, axis_names)``; 0.4.x takes a
-    single ``((name, size), ...)`` shape tuple.  Rule resolution and spec
-    tests only need ``.axis_names`` / ``.shape``, which both forms provide.
-    """
-    axis_sizes = tuple(axis_sizes)
+    """Device-free ``AbstractMesh`` with every axis ``Auto``."""
     axis_names = tuple(axis_names)
-    try:
-        return jax.sharding.AbstractMesh(axis_sizes, axis_names)
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), axis_names,
+                                     axis_types=_auto(len(axis_names)))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,11 +40,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     if multi_pod:
         shape = tuple(int(x) for x in os.environ.get(
             "REPRO_MULTI_SHAPE", "2,16,16").split(","))
-        return jax.make_mesh(shape, ("pod", "data", "model"))
-    return jax.make_mesh((16, 16), ("data", "model"))
+        return make_mesh(shape, ("pod", "data", "model"))
+    return make_mesh((16, 16), ("data", "model"))
 
 
 def make_host_mesh():
     """Whatever this host offers, as a 1D data mesh (tests/examples)."""
-    n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return make_mesh((len(jax.devices()), 1), ("data", "model"))

@@ -1,9 +1,9 @@
 """Roofline analysis from the compiled dry-run artifact (no real hardware).
 
-Terms (TPU v5e targets):
-  compute    = FLOPs_per_device            / 197e12  FLOP/s
-  memory     = bytes_accessed_per_device   / 819e9   B/s
-  collective = collective_bytes_per_device / 50e9    B/s (per-link ICI)
+Terms, for the target chip named by its ``device_kind`` (see ``PEAKS``):
+  compute    = FLOPs_per_device            / peak FLOP/s (bf16)
+  memory     = bytes_accessed_per_device   / peak HBM B/s
+  collective = collective_bytes_per_device / per-link ICI B/s
 
 ``cost_analysis()`` on the partitioned module reports per-device FLOPs/bytes;
 collective bytes are parsed from the optimized HLO text (per-device shapes):
@@ -21,9 +21,29 @@ from typing import Dict, Optional
 from .hlo_text import (COLLECTIVE_OPS as _COLLECTIVES, SHAPE_RE as _SHAPE_RE,
                        shape_bytes as _shape_bytes)
 
-PEAK_FLOPS = 197e12       # bf16 / chip
-HBM_BW = 819e9            # B/s / chip
-ICI_BW = 50e9             # B/s / link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float      # bf16 FLOP/s per chip
+    hbm_bw: float     # HBM bytes/s per chip
+    ici_bw: float     # ICI bytes/s per link
+
+
+#: Published per-chip peaks keyed by ``jax.Device.device_kind``.
+#: "TPU v5 lite" is TPU v5e (Google Cloud documentation, "TPU v5e"):
+#: 197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s ICI per chip over 4 links.
+PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8 / 4),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of ``device_kind``; a chip missing from ``PEAKS`` is an error."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 def collective_bytes(hlo_text: str) -> Dict[str, int]:
@@ -62,6 +82,7 @@ class Roofline:
     coll_bytes_per_device: float
     model_flops: float = 0.0       # 6*N*D (or 6*N_active*D)
     chips: int = 1
+    peak_flops: float = 0.0        # per chip, of the target device kind
 
     @property
     def dominant(self) -> str:
@@ -86,22 +107,24 @@ class Roofline:
         if not self.bound_seconds:
             return None
         return (self.model_flops
-                / (self.chips * PEAK_FLOPS * self.bound_seconds))
+                / (self.chips * self.peak_flops * self.bound_seconds))
 
 
 def analyze(flops_per_device: float, bytes_per_device: float,
-            coll: Dict[str, int], chips: int,
-            model_flops: float = 0.0) -> Roofline:
+            coll: Dict[str, int], chips: int, model_flops: float = 0.0,
+            *, device_kind: str) -> Roofline:
+    pk = peaks(device_kind)
     cb = float(sum(coll.values()))
     return Roofline(
-        compute_s=flops_per_device / PEAK_FLOPS,
-        memory_s=bytes_per_device / HBM_BW,
-        collective_s=cb / ICI_BW,
+        compute_s=flops_per_device / pk.flops,
+        memory_s=bytes_per_device / pk.hbm_bw,
+        collective_s=cb / pk.ici_bw,
         flops_per_device=flops_per_device,
         bytes_per_device=bytes_per_device,
         coll_bytes_per_device=cb,
         model_flops=model_flops,
         chips=chips,
+        peak_flops=pk.flops,
     )
 
 

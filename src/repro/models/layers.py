@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.distributed import sharding as shd
@@ -211,6 +212,26 @@ def _flash_mode() -> Optional[bool]:
     return True if jax.default_backend() == "tpu" else None
 
 
+def _flash_per_shard(qg, k, v, **kw):
+    """The Pallas flash kernel, run on each device's shard under a mesh.
+
+    GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
+    through ``shard_map``: batch split over the data axes, KV heads (and
+    their query groups) over 'model' where they divide, sequence whole.
+    """
+    from repro.kernels.flash_attention import flash_attention
+    call = functools.partial(flash_attention, **kw)
+    r = shd.get_rules()
+    if r is None or r.mesh is None:
+        return call(qg, k, v)
+    b = r.resolve("batch", qg.shape[0])
+    h = r.resolve("heads", qg.shape[2])
+    q_spec = P(b, None, h, None, None)
+    kv_spec = P(b, None, h, None)
+    return jax.shard_map(call, mesh=r.mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(qg, k, v)
+
+
 def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
               q_block: int, kv_block: int,
               window_override: Optional[int] = None,
@@ -243,9 +264,9 @@ def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
         kb = S
     flash = _flash_mode()
     if flash is not None and S % qb == 0 and k.shape[1] % kb == 0:
-        from repro.kernels.flash_attention import flash_attention
         qg = _grouped(q, cfg.n_kv_heads)
-        og = flash_attention(qg, k, v, causal, window, qb, kb, not flash)
+        og = _flash_per_shard(qg, k, v, causal=causal, window=window, bq=qb,
+                              bk=kb, interpret=not flash)
         o = og.reshape(B, S, -1, cfg.hd)
     else:
         o = blockwise_attention(q, k, v, causal=causal, window=window,

@@ -10,7 +10,9 @@ be:
 * any exception inside a step (injected in tests via ``failure_hook``)
   rolls back to the latest checkpoint and resumes — the data pipeline step
   counter restores from the checkpoint's extra dict so the batch sequence is
-  bit-identical;
+  bit-identical.  The step program is compiled once, before that retry
+  loop: a program the compiler refuses is raised at once, since restoring a
+  checkpoint cannot fix it;
 * restore goes through NamedShardings of the *current* mesh, so a run can
   resume on a different device count (elastic re-mesh) — exercised in
   tests/test_train_loop.py with different host-device meshes.
@@ -40,7 +42,7 @@ log = logging.getLogger("repro.train")
 class LoopConfig:
     total_steps: int = 100
     ckpt_every: int = 20
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: str = ""            # required: the loop restores what it finds
     keep: int = 3
     step_deadline_s: float = 120.0
     max_restarts: int = 3
@@ -58,6 +60,8 @@ def train(cfg: ModelConfig, rc: RunConfig, loop: LoopConfig,
           mesh=None, failure_hook: Optional[Callable[[int], None]] = None,
           log_every: int = 10) -> Dict[str, list]:
     """Run the loop; returns metric history."""
+    if not loop.ckpt_dir:
+        raise ValueError("LoopConfig.ckpt_dir must name the run's directory")
     rules = shd.Rules(mesh=mesh, seq_shard=rc.seq_shard, fsdp=rc.fsdp,
                       shard_vocab=rc.shard_vocab)
     with shd.use_rules(rules):
@@ -89,6 +93,8 @@ def train(cfg: ModelConfig, rc: RunConfig, loop: LoopConfig,
             return state
 
         state = restore_latest()
+        step_exec = jit_step.lower(
+            state, model_zoo.input_specs(cfg, rc)).compile()
         history: Dict[str, list] = {"loss": [], "step_time": [], "stragglers": 0,
                                     "restarts": 0}
         restarts = 0
@@ -103,7 +109,7 @@ def train(cfg: ModelConfig, rc: RunConfig, loop: LoopConfig,
                 # span wraps the traced call from outside (obs records
                 # nothing inside jit-compiled code — see repro.obs)
                 with obs.span("train/step", step=step_num, arch=cfg.name):
-                    state, metrics = jit_step(state, batch)
+                    state, metrics = step_exec(state, batch)
                     loss = float(jax.device_get(metrics["loss"]))
                 dt = time.monotonic() - t0
                 obs.hist_observe("train/step_ms", dt * 1e3, arch=cfg.name)

@@ -25,6 +25,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 from repro.configs import base                       # noqa: E402
 from repro.data.pipeline import SyntheticPipeline, device_batch  # noqa: E402
 from repro.distributed import sharding as shd        # noqa: E402
+from repro.launch.mesh import make_mesh              # noqa: E402
 from repro.models import model_zoo                   # noqa: E402
 from repro.train import step as ts                   # noqa: E402
 from repro.train.loop import LoopConfig, train       # noqa: E402
@@ -47,7 +48,7 @@ def _run_steps(cfg, rc, mesh, n_steps, seed=0):
 
 def scenario_compressed_grads():
     cfg = base.load_smoke("tinyllama-1.1b")
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     rc0 = base.RunConfig(seq_len=64, global_batch=8, kind="train",
                          remat=False, q_block=32, kv_block=32, lr=1e-3,
                          grad_compress_bits=0)
@@ -74,10 +75,10 @@ def scenario_remesh():
                         remat=False, q_block=32, kv_block=32, lr=1e-3)
     with tempfile.TemporaryDirectory() as d:
         loop = LoopConfig(total_steps=10, ckpt_every=5, ckpt_dir=d)
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         h1 = train(cfg, rc, loop, mesh=mesh_a, log_every=0)
         # resume the SAME run on a different device organization
-        mesh_b = jax.make_mesh((2, 4), ("data", "model"))
+        mesh_b = make_mesh((2, 4), ("data", "model"))
         loop2 = LoopConfig(total_steps=20, ckpt_every=5, ckpt_dir=d)
         h2 = train(cfg, rc, loop2, mesh=mesh_b, log_every=0)
         # reference: uninterrupted single-mesh run
@@ -95,7 +96,7 @@ def scenario_dist_equivalence():
     cfg = base.load_smoke("yi-9b")
     rc = base.RunConfig(seq_len=64, global_batch=8, kind="train",
                         remat=False, q_block=32, kv_block=32, lr=1e-3)
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh((2, 2), ("data", "model"))
     dist, _ = _run_steps(cfg, rc, mesh, 5)
     single, _ = _run_steps(cfg, rc, None, 5)
     print("dist:", dist, "single:", single)
