@@ -67,7 +67,9 @@ DECODE_PREFILL_RTOL = 5e-2
 # same run the logits moved by 1.8e-2 relative RMS.  0.1 is still below
 # both faults above.
 INT8_CACHE_RTOL = 0.1
-DIST_LOSS_ATOL = 5e-3      # what tests/test_distributed.py allows
+# what tests/test_distributed.py allows: sharded vs one device, and the
+# last loss of the 8-bit vs the plain gradient exchange
+DIST_LOSS_ATOL = 5e-3
 COMPRESSED_LOSS_ATOL = 0.35
 
 
