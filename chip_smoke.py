@@ -7,12 +7,13 @@
 One chip runs three phases in this one process:
 
 * serve: granite-8b at its published widths, depth cut to 8 of 36 layers,
-  random weights from ``--seed``.  ``ServeEngine.generate`` for 8 requests
+  random weights from ``SEED``.  ``ServeEngine.generate`` for 8 requests
   with a bf16 and an int8 KV cache; decode through the cache is checked
   against ``prefill`` (the flash kernel) on one prompt.
 * train: ``train.loop.train`` at the same widths, 3 layers, batch 2 at
   4096 tokens (what the compiled step's memory analysis fits in 16 GB),
-  flash forward and backward.
+  flash forward and backward; first the kernel's bf16 gradients are
+  checked against f32 attention at granite's head layout.
 * stencil/codec: the paper's kernels (jacobi, bitplane pack/unpack, KV
   quantization) against their jnp references in ``kernels/ref.py``.
 
@@ -67,10 +68,21 @@ DECODE_PREFILL_RTOL = 5e-2
 # same run the logits moved by 1.8e-2 relative RMS.  0.1 is still below
 # both faults above.
 INT8_CACHE_RTOL = 0.1
-# what tests/test_distributed.py allows: sharded vs one device, and the
-# last loss of the 8-bit vs the plain gradient exchange
+# Flash dq/dk/dv with bf16 inputs against f32 attention.  In interpret mode
+# on CPU (S 256-512, granite's head_dim) the relative RMS read 1.6e-3 to
+# 2.4e-3; a backward without the delta term read 0.35 to 0.63 on dq and dk,
+# one that summed dk/dv over the last query group only, 0.50 to 0.81.
+FLASH_GRAD_RTOL = 3e-2
+# what tests/test_distributed.py allows: sharded vs one device
 DIST_LOSS_ATOL = 5e-3
-COMPRESSED_LOSS_ATOL = 0.35
+# 8-bit vs plain gradient exchange, on every step.  On four v5e chips the
+# two differed by at most 9.6e-5 over 3 steps.  The losses fall mostly
+# because each step sees a new batch: a run with lr 0, which applies no
+# update at all, differed from the plain one by 6.7e-5 at step 2 and 9.4e-4
+# at step 3 (one v5e chip).  3e-4 lies 3x from either reading.
+COMPRESSED_LOSS_ATOL = 3e-4
+# every tolerance above was set from runs at this seed
+SEED = 0
 
 
 class CheckFailed(Exception):
@@ -137,16 +149,16 @@ def decode_through(engine: ServeEngine, tokens) -> np.ndarray:
     return np.asarray(logits[0], np.float32)
 
 
-def serve_phase(cfg: base.ModelConfig, seed: int, *, seq_len: int,
+def serve_phase(cfg: base.ModelConfig, *, seq_len: int,
                 prompt_lens, max_new: int) -> None:
-    rng = np.random.default_rng(seed)
+    rng = np.random.default_rng(SEED)
     prompts = [rng.integers(0, cfg.vocab, n).tolist() for n in prompt_lens]
     engines, outs = {}, {}
     for bits in (16, 8):
         rc = base.RunConfig(seq_len=seq_len, global_batch=len(prompts),
                             kind="decode", kv_cache_bits=bits)
         params = engines[16].params if engines else None
-        eng = ServeEngine(cfg, rc, params=params, seed=seed)
+        eng = ServeEngine(cfg, rc, params=params, seed=SEED)
         t0 = time.perf_counter()
         out = eng.generate(prompts, max_new=max_new)
         dt = time.perf_counter() - t0
@@ -185,8 +197,51 @@ def serve_phase(cfg: base.ModelConfig, seed: int, *, seq_len: int,
 # train
 # ---------------------------------------------------------------------------
 
+def flash_grad_errors(shape, bq: int, bk: int, interpret: bool = False):
+    """Relative RMS of the flash kernel's dq, dk, dv against the reference.
+
+    ``shape`` is (B, S, KV, G, D).  The kernel takes bf16 inputs, as in the
+    train step; the reference is ``blockwise_attention`` in f32 at full
+    matmul precision on the same rounded inputs.
+    """
+    from repro.kernels.flash_attention import flash_attention
+    from repro.models.layers import blockwise_attention
+    B, S, KV, G, D = shape
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(SEED), 3)
+    q = jax.random.normal(kq, shape, jnp.bfloat16)
+    k = jax.random.normal(kk, (B, S, KV, D), jnp.bfloat16)
+    v = jax.random.normal(kv, (B, S, KV, D), jnp.bfloat16)
+
+    def loss(o):
+        o = o.astype(jnp.float32)
+        return jnp.sum(o * jnp.cos(o))
+
+    def kernel_loss(q, k, v):
+        return loss(flash_attention(q, k, v, True, 0, bq, bk, interpret))
+
+    def ref_loss(q, k, v):
+        return loss(blockwise_attention(q.reshape(B, S, KV * G, D), k, v,
+                                        causal=True, window=0, q_block=bq,
+                                        kv_block=bk))
+
+    grads = jax.jit(jax.grad(kernel_loss, argnums=(0, 1, 2)))(q, k, v)
+    with jax.default_matmul_precision("highest"):
+        refs = jax.jit(jax.grad(ref_loss, argnums=(0, 1, 2)))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+    return {name: rel_rms(a.astype(jnp.float32), b)
+            for name, a, b in zip(("dq", "dk", "dv"), grads, refs)}
+
+
 def train_phase(cfg: base.ModelConfig, rc: base.RunConfig, steps: int,
                 out_dir: str) -> None:
+    shape = (1, 2048, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.hd)
+    errs = flash_grad_errors(shape, bq=512, bk=1024)
+    check("flash gradients (bf16) match f32 attention",
+          max(errs.values()) < FLASH_GRAD_RTOL,
+          f"(B, S, KV, G, D) {shape}: relative RMS " +
+          ", ".join(f"{n} {e:.2e}" for n, e in errs.items()) +
+          f" < {FLASH_GRAD_RTOL:g}")
+
     os.makedirs(out_dir, exist_ok=True)
     ckpt = tempfile.mkdtemp(prefix="train_ckpt_", dir=out_dir)
     try:
@@ -212,8 +267,8 @@ def same(a, b) -> bool:
         bool(jnp.array_equal(a, b))
 
 
-def stencil_codec_phase(seed: int, n: int, rows: int) -> None:
-    key = jax.random.PRNGKey(seed)
+def stencil_codec_phase(n: int, rows: int) -> None:
+    key = jax.random.PRNGKey(SEED)
     k1, k2, k3 = jax.random.split(key, 3)
 
     t_steps, width = 8, 512
@@ -252,7 +307,7 @@ def stencil_codec_phase(seed: int, n: int, rows: int) -> None:
 # four chips: sharded train step
 # ---------------------------------------------------------------------------
 
-def train_steps(cfg, rc, mesh, n_steps: int, seed: int):
+def train_steps(cfg, rc, mesh, n_steps: int):
     """``n_steps`` of the train step on ``mesh`` (None = one chip).
 
     Returns (losses, optimized HLO text).  State is created directly in its
@@ -276,8 +331,8 @@ def train_steps(cfg, rc, mesh, n_steps: int, seed: int):
             jit_init = jax.jit(init, out_shardings=sh)
             jit_step = jax.jit(step, in_shardings=(sh, None),
                                out_shardings=(sh, None), donate_argnums=(0,))
-        state = jit_init(jax.random.PRNGKey(seed))
-        pipe = SyntheticPipeline(cfg, rc, seed=seed)
+        state = jit_init(jax.random.PRNGKey(SEED))
+        pipe = SyntheticPipeline(cfg, rc, seed=SEED)
         batches = [device_batch(pipe.next(), cfg, rc) for _ in range(n_steps)]
         compiled = jit_step.lower(state, batches[0]).compile()
         losses = []
@@ -299,19 +354,19 @@ def u32_collectives(hlo: str) -> int:
     return sum(1 for line in hlo.splitlines() if _U32_COLLECTIVE.search(line))
 
 
-def four_chip_phase(cfg: base.ModelConfig, rc: base.RunConfig, seed: int,
+def four_chip_phase(cfg: base.ModelConfig, rc: base.RunConfig,
                     n: int) -> None:
     sharded, _ = train_steps(cfg, rc, make_mesh((2, 2), ("data", "model")),
-                             n, seed)
-    single, _ = train_steps(cfg, rc, None, n, seed)
+                             n)
+    single, _ = train_steps(cfg, rc, None, n)
     gap = max(abs(a - b) for a, b in zip(sharded, single))
     check("(data 2, model 2) train step == one-chip step", gap < DIST_LOSS_ATOL,
           f"losses {sharded} vs {single}; max gap {gap:.2e}")
 
     pods = make_mesh((2, 2), ("pod", "data"))
-    plain, hlo_plain = train_steps(cfg, rc, pods, n, seed)
+    plain, hlo_plain = train_steps(cfg, rc, pods, n)
     comp, hlo_comp = train_steps(
-        cfg, dataclasses.replace(rc, grad_compress_bits=8), pods, n, seed)
+        cfg, dataclasses.replace(rc, grad_compress_bits=8), pods, n)
     print("plain losses:", plain, "8-bit losses:", comp, flush=True)
     print("collective bytes per device, plain:",
           roofline.collective_bytes(hlo_plain), "8-bit:",
@@ -320,10 +375,10 @@ def four_chip_phase(cfg: base.ModelConfig, rc: base.RunConfig, seed: int,
     check("quantized exchange is in the compiled 8-bit step",
           n_comp > 0 and n_plain == 0,
           f"{n_comp} uint32 collectives (plain step: {n_plain})")
+    gap = max(abs(a - b) for a, b in zip(comp, plain))
     check("8-bit exchange tracks the plain exchange",
-          abs(comp[-1] - plain[-1]) < COMPRESSED_LOSS_ATOL
-          and all(np.isfinite(comp)),
-          f"last loss {comp[-1]:.4f} vs {plain[-1]:.4f}")
+          gap < COMPRESSED_LOSS_ATOL and all(np.isfinite(comp)),
+          f"max gap {gap:.2e} over {n} steps < {COMPRESSED_LOSS_ATOL:g}")
 
 
 # ---------------------------------------------------------------------------
@@ -331,7 +386,6 @@ def four_chip_phase(cfg: base.ModelConfig, rc: base.RunConfig, seed: int,
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", default=os.path.join(ROOT, ".chip_smoke"),
                     help="scratch directory (training checkpoints)")
     args = ap.parse_args(argv)
@@ -358,7 +412,7 @@ def main(argv=None) -> int:
     if args.chips == 4:
         phases = [("four_chip", lambda: four_chip_phase(
             granite(2), base.RunConfig(seq_len=2048, global_batch=4,
-                                       kind="train"), args.seed, n=3))]
+                                       kind="train"), n=3))]
     else:
         serve_cfg = granite(8)
         print(f"serve model: granite-8b widths, 8 of 36 layers, "
@@ -366,14 +420,14 @@ def main(argv=None) -> int:
               f"({serve_cfg.param_count() * 2 / 1e9:.1f} GB bf16)", flush=True)
         phases = [
             ("serve", lambda: serve_phase(
-                serve_cfg, args.seed, seq_len=2048,
+                serve_cfg, seq_len=2048,
                 prompt_lens=[128 * i for i in range(1, 9)], max_new=32)),
             ("train", lambda: train_phase(
                 granite(3), base.RunConfig(seq_len=4096, global_batch=2,
                                            kind="train"),
                 steps=4, out_dir=args.out)),
             ("stencil_codec", lambda: stencil_codec_phase(
-                args.seed, n=1 << 26, rows=32768)),
+                n=1 << 26, rows=32768)),
         ]
     for name, fn in phases:
         snap = log.snapshot()

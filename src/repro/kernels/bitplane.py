@@ -15,7 +15,8 @@ re-expressed for the TPU VPU:
 Tiling: codes are processed in (BM, BLOCK) VMEM tiles, BLOCK a multiple of
 32 lanes x groups; packed planes live in (BM, BLOCK//32*bits) tiles.  The
 kernels work in int32; the uint32 plane words are a free bitcast outside.
-BM = 128 makes the in-kernel transposes whole (128, 128) tiles.
+BM = 128 makes the in-kernel transposes whole (128, 128) tiles; a row
+count above 128 is padded to a multiple of it (``tile_rows``).
 """
 from __future__ import annotations
 
@@ -26,7 +27,19 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 GROUP = 32
-DEF_BM = 128  # rows per tile (smaller arrays take one tile)
+DEF_BM = 128  # rows per tile
+
+
+def tile_rows(x: jax.Array, bm: int):
+    """(x, rows per tile) for a grid over the leading axis.
+
+    Up to ``bm`` rows make one whole-array tile.  More are padded with zero
+    rows to a multiple of ``bm``; the caller slices its result back.
+    """
+    n = x.shape[0]
+    if n <= bm:
+        return x, n
+    return jnp.pad(x, ((0, -n % bm), (0, 0))), bm
 
 
 def _delta_lanes(v: jax.Array) -> jax.Array:
@@ -82,18 +95,18 @@ def pack(q: jax.Array, *, bits: int, block: int, bm: int = DEF_BM,
          interpret: bool = False) -> jax.Array:
     """int32 codes [N, block] -> packed planes uint32 [N, block//32*bits]."""
     n = q.shape[0]
-    bm = min(bm, n)
-    assert q.shape == (n, block) and n % bm == 0, (q.shape, bm)
+    assert q.shape == (n, block), (q.shape, block)
     pw = block // GROUP * bits
+    q, bm = tile_rows(q, bm)
     out = pl.pallas_call(
         functools.partial(_pack_kernel, bits=bits, block=block),
-        grid=(n // bm,),
+        grid=(q.shape[0] // bm,),
         in_specs=[pl.BlockSpec((bm, block), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, pw), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, pw), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((q.shape[0], pw), jnp.int32),
         interpret=interpret,
     )(q)
-    return jax.lax.bitcast_convert_type(out, jnp.uint32)
+    return jax.lax.bitcast_convert_type(out[:n], jnp.uint32)
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "block", "bm", "interpret"))
@@ -101,15 +114,14 @@ def unpack(planes: jax.Array, *, bits: int, block: int, bm: int = DEF_BM,
            interpret: bool = False) -> jax.Array:
     """Packed planes uint32 [N, block//32*bits] -> int32 codes [N, block]."""
     n = planes.shape[0]
-    bm = min(bm, n)
     pw = block // GROUP * bits
-    assert planes.shape == (n, pw) and n % bm == 0, (planes.shape, pw, bm)
-    planes = jax.lax.bitcast_convert_type(planes, jnp.int32)
+    assert planes.shape == (n, pw), (planes.shape, pw)
+    planes, bm = tile_rows(jax.lax.bitcast_convert_type(planes, jnp.int32), bm)
     return pl.pallas_call(
         functools.partial(_unpack_kernel, bits=bits, block=block),
-        grid=(n // bm,),
+        grid=(planes.shape[0] // bm,),
         in_specs=[pl.BlockSpec((bm, pw), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((bm, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, block), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((planes.shape[0], block), jnp.int32),
         interpret=interpret,
-    )(planes)
+    )(planes)[:n]

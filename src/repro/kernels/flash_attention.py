@@ -14,6 +14,8 @@ Scores are computed transposed, sT = k q^T of shape (bk, bq): keys on
 sublanes, queries on lanes.  Every per-query statistic (running max, sum,
 lse, delta) is then a lane-dense (1, bq) row, and lse/delta are stored as
 (B, KV, G, 1, S) arrays whose blocks (1, bq) are whole on the sublane dim.
+Scores take q/k in their own dtype with f32 accumulation; p and ds stay
+f32 in every matmul they enter, as in the XLA blockwise path.
 
 Grid (B, KV, G, nq, nk): nk innermost, online-softmax state (m, l, acc)
 carried in VMEM scratch across the nk sweep.  Forward + backward (dq, dk,
@@ -79,7 +81,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
     alpha = jnp.exp(m_prev - m_new)
     p = jnp.exp(s - m_new)
     l_scr[...] = l_scr[...] * alpha + p.sum(axis=0, keepdims=True)
-    acc_scr[...] = acc_scr[...] * alpha + _dot(v, p.astype(v.dtype), _TN)
+    acc_scr[...] = acc_scr[...] * alpha + _dot(v.astype(F32), p, _TN)
     m_scr[...] = m_new
 
     @pl.when(ki == nk - 1)
@@ -158,8 +160,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     q, _, do, p, ds = _bwd_block(
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, bq=bq,
         bk=bk, causal=causal, window=window, scale=scale)
-    dv_scr[...] += _dot(p.astype(do.dtype), do, (((1,), (0,)), ((), ())))
-    dk_scr[...] += _dot(ds.astype(q.dtype), q, (((1,), (0,)), ((), ())))
+    dv_scr[...] += _dot(p, do.astype(F32), (((1,), (0,)), ((), ())))
+    dk_scr[...] += _dot(ds, q.astype(F32), (((1,), (0,)), ((), ())))
 
     @pl.when(jnp.logical_and(gi == ng - 1, qi == nq - 1))
     def _finish():
@@ -179,7 +181,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     _, k, _, _, ds = _bwd_block(
         q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, bq=bq,
         bk=bk, causal=causal, window=window, scale=scale)
-    dq_scr[...] += _dot(ds.astype(k.dtype), k, _TN)   # (bq, D)
+    dq_scr[...] += _dot(ds, k.astype(F32), _TN)       # (bq, D)
 
     @pl.when(ki == nk - 1)
     def _finish():

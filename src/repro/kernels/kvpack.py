@@ -10,7 +10,8 @@ Kernels:
   * dequant: inverse, used on the attention read path.
 
 Tiling: (BM, d) VMEM tiles; d is the head_dim (128-aligned in all assigned
-architectures).
+architectures).  Rows beyond one tile are padded to a whole number of
+tiles (``bitplane.tile_rows``).
 """
 from __future__ import annotations
 
@@ -20,7 +21,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEF_BM = 128  # rows per tile (smaller arrays take one tile)
+from repro.kernels.bitplane import tile_rows
+
+DEF_BM = 128  # rows per tile
 
 
 def _quant_kernel(x_ref, q_ref, s_ref, *, bits: int):
@@ -61,23 +64,25 @@ def kv_quant(x: jax.Array, *, bits: int = 8, bm: int = DEF_BM,
              interpret: bool = False):
     """[rows, d] float -> (codes int8, scales f32 [rows, 1])."""
     rows, d = x.shape
-    bm = min(bm, rows)
-    assert rows % bm == 0 and (bits == 8 or d % 2 == 0)
+    assert bits == 8 or d % 2 == 0
     cd = d if bits == 8 else d // 2
-    return pl.pallas_call(
+    x, bm = tile_rows(x, bm)
+    padded = x.shape[0]
+    codes, scales = pl.pallas_call(
         functools.partial(_quant_kernel, bits=bits),
-        grid=(rows // bm,),
+        grid=(padded // bm,),
         in_specs=[pl.BlockSpec((bm, d), lambda i: (i, 0))],
         out_specs=[
             pl.BlockSpec((bm, cd), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, cd), jnp.int8),
-            jax.ShapeDtypeStruct((rows, 1), jnp.float32),
+            jax.ShapeDtypeStruct((padded, cd), jnp.int8),
+            jax.ShapeDtypeStruct((padded, 1), jnp.float32),
         ],
         interpret=interpret,
     )(x)
+    return codes[:rows], scales[:rows]
 
 
 @functools.partial(jax.jit, static_argnames=("bits", "bm", "interpret"))
@@ -85,16 +90,17 @@ def kv_dequant(codes: jax.Array, scales: jax.Array, *, bits: int = 8,
                bm: int = DEF_BM, interpret: bool = False) -> jax.Array:
     rows, cd = codes.shape
     d = cd if bits == 8 else cd * 2
-    bm = min(bm, rows)
-    assert rows % bm == 0
+    codes, bm = tile_rows(codes, bm)
+    scales, _ = tile_rows(scales, bm)
+    padded = codes.shape[0]
     return pl.pallas_call(
         functools.partial(_dequant_kernel, bits=bits),
-        grid=(rows // bm,),
+        grid=(padded // bm,),
         in_specs=[
             pl.BlockSpec((bm, cd), lambda i: (i, 0)),
             pl.BlockSpec((bm, 1), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((bm, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((padded, d), jnp.float32),
         interpret=interpret,
-    )(codes, scales)
+    )(codes, scales)[:rows]

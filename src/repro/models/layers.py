@@ -212,24 +212,49 @@ def _flash_mode() -> Optional[bool]:
     return True if jax.default_backend() == "tpu" else None
 
 
-def _flash_per_shard(qg, k, v, **kw):
+def _flash_per_shard(q, k, v, **kw):
     """The Pallas flash kernel, run on each device's shard under a mesh.
 
-    GSPMD cannot partition a Mosaic kernel, so under a mesh the call goes
-    through ``shard_map``: batch split over the data axes, KV heads (and
-    their query groups) over 'model' where they divide, sequence whole.
+    q: (B, S, H, D); k, v: (B, S, KV, D) -> (B, S, H, D).  GSPMD cannot
+    partition a Mosaic kernel, so under a mesh the call goes through
+    ``shard_map``: batch split over the data axes, query heads over 'model'
+    where they divide, sequence whole.  Where the KV heads divide 'model'
+    too, each shard takes its own KV heads.  Where they do not (e.g. 8 KV
+    heads on a model axis of 16) but each shard's query heads share one KV
+    head, k/v stay whole and each shard slices that head.  Otherwise every
+    shard computes every head.
     """
     from repro.kernels.flash_attention import flash_attention
-    call = functools.partial(flash_attention, **kw)
+    KV = k.shape[2]
+
+    def call(q, k, v):
+        o = flash_attention(_grouped(q, k.shape[2]), k, v, **kw)
+        return o.reshape(q.shape)
+
     r = shd.get_rules()
     if r is None or r.mesh is None:
-        return call(qg, k, v)
-    b = r.resolve("batch", qg.shape[0])
-    h = r.resolve("heads", qg.shape[2])
-    q_spec = P(b, None, h, None, None)
-    kv_spec = P(b, None, h, None)
-    return jax.shard_map(call, mesh=r.mesh, in_specs=(q_spec, kv_spec, kv_spec),
-                         out_specs=q_spec, check_vma=False)(qg, k, v)
+        return call(q, k, v)
+    b = r.resolve("batch", q.shape[0])
+    h = r.resolve("heads", q.shape[2])
+    G = q.shape[2] // KV
+    local = q.shape[2] // r.axis_size(h) if h else 0
+    q_spec = P(b, None, h, None)
+    if h is None or r.resolve("heads", KV) == h:
+        kv_spec = P(b, None, h, None)
+        body = call
+    elif G % local == 0:
+        kv_spec = P(b, None, None, None)
+
+        def body(q, k, v):
+            head = jax.lax.axis_index(h) * local // G
+            one = functools.partial(jax.lax.dynamic_slice_in_dim, start_index=head,
+                                    slice_size=1, axis=2)
+            return call(q, one(k), one(v))
+    else:
+        q_spec = kv_spec = P(b, None, None, None)
+        body = call
+    return jax.shard_map(body, mesh=r.mesh, in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)(q, k, v)
 
 
 def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
@@ -264,10 +289,8 @@ def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
         kb = S
     flash = _flash_mode()
     if flash is not None and S % qb == 0 and k.shape[1] % kb == 0:
-        qg = _grouped(q, cfg.n_kv_heads)
-        og = _flash_per_shard(qg, k, v, causal=causal, window=window, bq=qb,
-                              bk=kb, interpret=not flash)
-        o = og.reshape(B, S, -1, cfg.hd)
+        o = _flash_per_shard(q, k, v, causal=causal, window=window, bq=qb,
+                             bk=kb, interpret=not flash)
     else:
         o = blockwise_attention(q, k, v, causal=causal, window=window,
                                 q_block=qb, kv_block=kb)

@@ -8,6 +8,9 @@ Scenarios:
   remesh            — train on mesh A, checkpoint, restore on mesh B
                       (elastic re-mesh), losses must continue identically
   dist_equivalence  — sharded (2,2) mesh train step == single-device step
+  flash_heads       — the flash kernel per shard on a (2,4) mesh, KV heads
+                      dividing 'model', split across it, or replicated:
+                      output and gradients == the unsharded call
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -104,9 +107,38 @@ def scenario_dist_equivalence():
     print("OK dist_equivalence")
 
 
+def scenario_flash_heads():
+    from repro.models import layers
+    mesh = make_mesh((2, 4), ("data", "model"))
+    B, S, D = 2, 128, 64
+    kw = dict(causal=True, window=0, bq=64, bk=64, interpret=True)
+
+    def loss(q, k, v):
+        o = layers._flash_per_shard(q, k, v, **kw)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    # (KV, G): KV divides model=4; one KV head per 2 shards; one for all 4;
+    # 6 query heads do not divide 4 (every shard computes every head)
+    for KV, G in [(4, 2), (2, 4), (1, 8), (3, 2)]:
+        rng = np.random.default_rng(KV * 10 + G)
+        q, k, v = (jnp.asarray(rng.standard_normal(s), jnp.float32) for s in
+                   [(B, S, KV * G, D), (B, S, KV, D), (B, S, KV, D)])
+        # a fresh jit each time: the rules are read when the call is traced
+        fn = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+            loss, argnums=(0, 1, 2), has_aux=True))
+        (_, o_ref), g_ref = fn()(q, k, v)
+        with shd.use_rules(shd.Rules(mesh=mesh)):
+            (_, o), g = fn()(q, k, v)
+        for name, a, b in zip("o dq dk dv".split(), (o, *g), (o_ref, *g_ref)):
+            rel = float(jnp.abs(a - b).max() / jnp.abs(b).max())
+            assert rel < 1e-5, (KV, G, name, rel)
+    print("OK flash_heads")
+
+
 if __name__ == "__main__":
     {
         "compressed_grads": scenario_compressed_grads,
         "remesh": scenario_remesh,
         "dist_equivalence": scenario_dist_equivalence,
+        "flash_heads": scenario_flash_heads,
     }[sys.argv[1]]()

@@ -35,3 +35,7 @@ def test_compressed_grads():
 @pytest.mark.slow
 def test_remesh():
     _run("remesh")
+
+
+def test_flash_heads_per_shard():
+    _run("flash_heads")

@@ -8,7 +8,9 @@ from repro.kernels import ops, ref
 
 
 @pytest.mark.parametrize("bits", [4, 6, 8, 12, 16])
-@pytest.mark.parametrize("n,block", [(8, 128), (16, 256), (32, 512)])
+# 136 and 256 rows run two 128-row tiles (136 padded to 256)
+@pytest.mark.parametrize("n,block", [(8, 128), (16, 256), (32, 512), (136, 128),
+                                     (256, 256)])
 def test_bitplane_pack_unpack_sweep(bits, n, block):
     rng = np.random.default_rng(bits * n)
     lim = max(1 << (bits - 2), 1)
@@ -23,7 +25,8 @@ def test_bitplane_pack_unpack_sweep(bits, n, block):
 
 
 @pytest.mark.parametrize("bits", [8, 4])
-@pytest.mark.parametrize("rows,d", [(8, 128), (32, 128), (16, 256)])
+@pytest.mark.parametrize("rows,d", [(8, 128), (32, 128), (16, 256), (136, 128),
+                                    (256, 128)])
 @pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
 def test_kv_quant_sweep(bits, rows, d, dtype):
     rng = np.random.default_rng(rows)

@@ -128,3 +128,29 @@ def test_sharded_train_step_compiles_for_v5e_2x2(v5e_chips, monkeypatch):
                            out_shardings=(sh, None)).lower(state,
                                                            batch).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_split_kv_heads_compiles_for_v5e_1x4(v5e_chips):
+    """2 KV heads on a model axis of 4: each shard slices the KV head its
+    two query heads share, forward and backward."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.distributed import sharding as shd
+    from repro.launch.mesh import make_mesh
+    from repro.models import layers
+
+    mesh = make_mesh((1, 4), ("data", "model"), devices=v5e_chips)
+    heads = NamedSharding(mesh, P(None, None, "model", None))
+    whole = NamedSharding(mesh, P())
+    q = jax.ShapeDtypeStruct((1, 1024, 8, D), bf16, sharding=heads)
+    kv = jax.ShapeDtypeStruct((1, 1024, 2, D), bf16, sharding=whole)
+
+    def loss(q, k, v):
+        o = layers._flash_per_shard(q, k, v, causal=True, window=0, bq=512,
+                                    bk=512, interpret=False)
+        return jnp.sum(o.astype(jnp.float32))
+
+    with shd.use_rules(shd.Rules(mesh=mesh)):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    assert "tpu_custom_call" in compiled.as_text()
