@@ -58,10 +58,11 @@ class SyntheticPipeline:
         return toks.astype(np.int32)
 
     def next(self) -> Dict[str, Any]:
-        if not obs.enabled():
-            return self._next()
         t0 = time.perf_counter()
-        batch = self._next()
+        with obs.span("data/next"):
+            batch = self._next()
+        if not obs.enabled():
+            return batch
         obs.hist_observe("data/batch_ms", (time.perf_counter() - t0) * 1e3,
                          arch=self.cfg.name)
         obs.counter_inc("data/batches", 1, arch=self.cfg.name)
@@ -105,11 +106,13 @@ def device_batch(batch: Dict[str, Any], cfg: ModelConfig, rc: RunConfig,
     """Cast to the cell's input dtypes and place on device(s)."""
     specs = model_zoo.input_specs(cfg, rc)
     out = {}
-    for k, v in batch.items():
-        spec = specs[k]
-        arr = np.asarray(v)
-        sh = shardings.get(k) if shardings else None
-        out[k] = jax.device_put(arr, sh) if sh is not None else jax.device_put(arr)
-        if out[k].dtype != spec.dtype:
-            out[k] = out[k].astype(spec.dtype)
+    with obs.span("data/to_device"):
+        for k, v in batch.items():
+            spec = specs[k]
+            arr = np.asarray(v)
+            sh = shardings.get(k) if shardings else None
+            out[k] = (jax.device_put(arr, sh) if sh is not None
+                      else jax.device_put(arr))
+            if out[k].dtype != spec.dtype:
+                out[k] = out[k].astype(spec.dtype)
     return out

@@ -415,7 +415,10 @@ def decode_attention(x: jax.Array, p: AttnParams, cfg: ModelConfig,
     ring = window > 0 and S <= window
     slot = pos % S if ring else pos
     q, k_new, v_new = _qkv(x, p, cfg, pos[:, None])
-    cache = update_cache(cache, k_new, v_new, slot, bits)
+    # the new row's quantize and its write into the cache, scoped for the
+    # benchmark's device trace (kv_write_ms.serve)
+    with jax.named_scope("kv_cache_write"):
+        cache = update_cache(cache, k_new, v_new, slot, bits)
 
     # the dequant + attention region deploys as a fused Pallas kernel on TPU
     # (kernels/kvpack dequant fused into flash-decode): codes are read from

@@ -1,12 +1,27 @@
 """Enable-gated instrumentation facade — the only obs API hot paths touch.
 
-Design rule: *zero cost when disabled*.  Every helper starts with a single
-module-flag test and returns immediately when obs is off; the disabled
-``span()`` returns a shared null context (no allocation, no clock read).
+One span, two sinks.  ``span(name)`` writes a host event named ``name`` into
+the ``jax.profiler`` trace (a ``TraceAnnotation``), so a span shares the
+profiler's clock with the device's ops; with obs enabled it also records
+into the ``Tracer`` (its own ``perf_counter`` clock, Chrome-trace export).
+The annotation passes the name only: the span's args go to the ``Tracer``.
+
+Cost when obs is disabled: counters, gauges and histograms are a single
+module-flag test; ``span()`` adds one test of whether a profiler session is
+active (about 20 ns), and returns a shared null context when none is (no
+allocation, no clock read) — an annotation would record nothing then.
+Under an active profiler session it enters the annotation, which records
+an event: a few microseconds of host time per span.
+
+A span times host code.  Around a jitted call or a kernel wrapper (the
+``kernels/<name>`` spans of ``kernels/ops.py``) it times the dispatch, not
+the device's work, unless the host waits for the result inside it.
+
 Instrumentation must sit *around* ``jax.jit``-traced calls, never inside
 them — a traced function runs as compiled XLA where Python side effects
 do not execute (and would otherwise bake constants into the trace), so
 callers record around ``jit_step(...)`` / ``self._step(...)`` boundaries.
+Device-side regions are named with ``jax.named_scope`` instead.
 
 Enable globally with ``REPRO_OBS=1`` in the environment, or per-scope::
 
@@ -23,6 +38,8 @@ import os
 import time
 from contextlib import contextmanager
 from typing import Callable, Iterator, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from . import metrics as _metrics
 from . import trace as _trace
@@ -120,7 +137,7 @@ def hist_observe(name: str, value: float, **labels) -> None:
 
 
 class _NullSpan:
-    """Inert stand-in yielded by the disabled ``span()``."""
+    """Inert stand-in yielded by a span that the ``Tracer`` does not record."""
     __slots__ = ()
     cycles = 0
 
@@ -145,18 +162,44 @@ class _NullCtx:
 _NULL_CTX = _NullCtx()
 
 
+class _Annotated:
+    """The profiler's annotation alone: the disabled span under a session."""
+    __slots__ = ("_annotation",)
+
+    def __init__(self, name: str):
+        self._annotation = TraceAnnotation(name)
+
+    def __enter__(self) -> _NullSpan:
+        self._annotation.__enter__()
+        return _NullCtx._span
+
+    def __exit__(self, *exc) -> bool:
+        self._annotation.__exit__(*exc)
+        return False
+
+
+@contextmanager
+def _recorded(name: str, args: dict) -> Iterator[_trace.Span]:
+    with TraceAnnotation(name), _tracer.span(name, **args) as sp:
+        yield sp
+
+
 def span(name: str, **args):
-    """Context manager: live tracer span when enabled, shared no-op if not."""
-    if not _enabled:
-        return _NULL_CTX
-    return _tracer.span(name, **args)
+    """Context manager: a host span in the profiler's trace, and a live
+    ``Tracer`` span when obs is enabled; the shared no-op context when
+    neither records."""
+    if _enabled:
+        return _recorded(name, args)
+    if TraceAnnotation.is_enabled():
+        return _Annotated(name)
+    return _NULL_CTX
 
 
 def instrumented(name: Optional[str] = None, **labels
                  ) -> Callable[[Callable], Callable]:
     """Decorator: wrap calls in a span + ``<name>_ms`` latency histogram.
 
-    The wrapper costs one flag test per call when disabled.  Apply to
+    Disabled, the wrapper costs what a disabled ``span()`` costs.  Apply to
     *host-side* functions only — never to a function that will itself be
     ``jax.jit``-traced (see module docstring).
     """
@@ -166,9 +209,10 @@ def instrumented(name: Optional[str] = None, **labels
         @functools.wraps(fn)
         def wrapper(*a, **kw):
             if not _enabled:
-                return fn(*a, **kw)
+                with span(metric):
+                    return fn(*a, **kw)
             t0 = time.perf_counter()
-            with _tracer.span(metric, **labels):
+            with _recorded(metric, labels):
                 out = fn(*a, **kw)
             _registry.histogram(f"{metric}_ms", **labels).observe(
                 (time.perf_counter() - t0) * 1e3)

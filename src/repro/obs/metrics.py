@@ -3,13 +3,13 @@
 The paper's figure of merit is *measured* (§5: on-FPGA I/O-cycle counters),
 so the reproduction keeps the same discipline in software: every hot-path
 quantity — transfer cycles per access pattern, compressed vs padded bits,
-executor tile counts, train step latency, serve KV bytes — is published
+executor tile counts, batch feed latency, serve KV bytes — is published
 into a registry that benchmarks and tests can snapshot and assert against.
 
 Naming conventions (see ``src/repro/obs/README.md``):
 
 * metric names are ``<subsystem>/<quantity>`` (``transfer/cycles``,
-  ``compression/ratio``, ``train/step_ms``);
+  ``compression/ratio``, ``ckpt/save_ms``);
 * labels qualify a series (``pattern=mars_comp``, ``dtype=fixed18``); every
   distinct label set is an independent series;
 * counters are monotonically accumulated ints/floats, gauges hold the last

@@ -16,7 +16,7 @@ Tolerance policy (``GATES``):
   **exactly** (float epsilon only).  Any drift in the bad direction fails;
   drift in the good direction is reported as ``improved`` with a reminder
   to refresh the baseline.
-* **wall-clock** series (``ckpt/save_ms``, ``train/step_ms``, ...) get a
+* **wall-clock** series (``ckpt/save_ms``, ``data/batch_ms``, ...) get a
   **percentage band** (``--wall-tol``, default allow 3x over baseline)
   because absolute times vary machine to machine; the band only catches
   order-of-magnitude pathology, the logical counters are the real gate.
@@ -79,8 +79,6 @@ GATES: List[Tuple[str, str, str]] = [
     ("ckpt/bytes_read", "lower", EXACT),
     ("ckpt/save_ms", "lower", WALL),
     ("ckpt/restore_ms", "lower", WALL),
-    ("train/step_ms", "lower", WALL),
-    ("serve/generate_ms", "lower", WALL),
     ("data/batch_ms", "lower", WALL),
     ("analysis/findings", "lower", EXACT),
     ("analysis/new_findings", "lower", EXACT),

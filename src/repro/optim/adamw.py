@@ -55,6 +55,9 @@ def global_norm(tree) -> jax.Array:
                         for x in jax.tree.leaves(tree)))
 
 
+# the global norm, the clip and the update, scoped for the benchmark's
+# device trace (adamw_ms.train)
+@jax.named_scope("adamw_update")
 def update(grads, state: AdamState, params, cfg: AdamConfig
            ) -> Tuple[object, AdamState]:
     count = state.count + 1

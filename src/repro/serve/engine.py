@@ -10,7 +10,6 @@ per-row scale markers (§2.4 packing + §4.2.2 metadata).
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import List, Optional
 
 import jax
@@ -62,34 +61,34 @@ class ServeEngine:
             obs.gauge_set("serve/kv_bytes", int(self.kv_cache_bytes(B)),
                           arch=self.cfg.name,
                           kv_bits=self.rc.kv_cache_bits)
-        t_start = time.perf_counter()
         # spans/counters wrap the jitted decode step from outside; nothing
-        # records inside the traced function (see repro.obs)
+        # records inside the traced function (see repro.obs).  Per step:
+        # dispatch, the host's wait for the step (fetch), the next tokens.
         with obs.span("serve/generate", arch=self.cfg.name, batch=B,
                       max_new=max_new):
             state = self.api.init_decode_state(B)
             out_tokens = [[] for _ in range(B)]
             cur = prompt_buf[:, 0].copy()
             for t in range(total - 1):
-                logits, state = self._step(self.params, state,
-                                           jnp.asarray(cur, jnp.int32))
-                nxt_model = np.asarray(jnp.argmax(logits, axis=-1))
-                nxt = np.zeros((B,), np.int32)
-                for i in range(B):
-                    if t + 1 < lens[i]:
-                        nxt[i] = prompt_buf[i, t + 1]   # still in prompt
-                    else:
-                        nxt[i] = nxt_model[i]
-                        if len(out_tokens[i]) < max_new:
-                            out_tokens[i].append(int(nxt_model[i]))
-                cur = nxt
+                with obs.span("serve/dispatch"):
+                    logits, state = self._step(self.params, state,
+                                               jnp.asarray(cur, jnp.int32))
+                with obs.span("serve/fetch"):
+                    nxt_model = np.asarray(jnp.argmax(logits, axis=-1))
+                with obs.span("serve/select"):
+                    nxt = np.zeros((B,), np.int32)
+                    for i in range(B):
+                        if t + 1 < lens[i]:
+                            nxt[i] = prompt_buf[i, t + 1]   # still in prompt
+                        else:
+                            nxt[i] = nxt_model[i]
+                            if len(out_tokens[i]) < max_new:
+                                out_tokens[i].append(int(nxt_model[i]))
+                    cur = nxt
         if obs.enabled():
             n_gen = sum(len(t) for t in out_tokens)
             obs.counter_inc("serve/generated_tokens", n_gen,
                             arch=self.cfg.name)
             obs.counter_inc("serve/decode_steps", total - 1,
                             arch=self.cfg.name)
-            obs.hist_observe("serve/generate_ms",
-                             (time.perf_counter() - t_start) * 1e3,
-                             arch=self.cfg.name)
         return out_tokens

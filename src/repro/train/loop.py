@@ -112,7 +112,6 @@ def train(cfg: ModelConfig, rc: RunConfig, loop: LoopConfig,
                     state, metrics = step_exec(state, batch)
                     loss = float(jax.device_get(metrics["loss"]))
                 dt = time.monotonic() - t0
-                obs.hist_observe("train/step_ms", dt * 1e3, arch=cfg.name)
                 obs.gauge_set("train/loss", loss, arch=cfg.name)
                 obs.counter_inc("train/steps", 1, arch=cfg.name)
                 obs.counter_inc("train/tokens",
