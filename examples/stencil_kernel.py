@@ -1,8 +1,9 @@
 """The §4 macro-pipeline as a Pallas TPU kernel (interpret-mode demo).
 
-Chunked jacobi-1d: each grid step DMAs one chunk HBM->VMEM, advances it T
-time steps, carries the inter-tile MARS (2 columns x T levels) through VMEM
-scratch — irredundant inter-tile dataflow, per the paper.
+Chunked jacobi-1d: each grid step DMAs a block of whole W-cell tiles
+HBM->VMEM, advances it T time steps, carries the inter-tile MARS (2 cells x
+T levels) through vector registers inside the block and VMEM scratch
+between blocks — irredundant inter-tile dataflow, per the paper.
 
 Run:  PYTHONPATH=src python examples/stencil_kernel.py
 """

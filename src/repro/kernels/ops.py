@@ -175,6 +175,14 @@ def kv_dequant(codes: jax.Array, scales: jax.Array, bits: int = 8,
 # Chunked jacobi (stencil macro-pipeline demo)
 # ---------------------------------------------------------------------------
 
+def _jacobi_padded_cells(n: int, t_steps: int, width: int) -> int:
+    """Cells the kernel runs over: a ghost tile, the field, at least T edge
+    cells, rounded up to whole blocks."""
+    least = width + n + t_steps
+    block = jacobi_mars.block_rows(least, width) * jacobi_mars.LANES
+    return -(-least // block) * block
+
+
 @functools.partial(jax.jit, static_argnames=("t_steps", "width", "use_pallas"))
 def _jacobi1d_tiled_jit(x: jax.Array, t_steps: int, width: int,
                         use_pallas: str | bool) -> jax.Array:
@@ -183,7 +191,7 @@ def _jacobi1d_tiled_jit(x: jax.Array, t_steps: int, width: int,
         return ref.jacobi_chunked_ref(x, t_steps)
     n = x.shape[0]
     assert t_steps < width - 2, (t_steps, width)
-    pad_right = (-(n + width + t_steps)) % width + t_steps
+    pad_right = _jacobi_padded_cells(n, t_steps, width) - n - width
     xp = jnp.concatenate([
         jnp.full((width,), x[0], dtype=jnp.float32),
         x.astype(jnp.float32),
@@ -198,12 +206,13 @@ def jacobi1d_tiled(x: jax.Array, t_steps: int, width: int = 512,
                    use_pallas: str | bool = "auto") -> jax.Array:
     """T jacobi steps (edge-padded open-boundary contract), chunked execution.
 
-    The kernel runs over a padded domain: one full ghost chunk of x[0] on the
-    left (so the first real chunk's carry is exact — the frozen far-left
+    The kernel runs over a padded domain: one full ghost tile of x[0] on the
+    left (so the first real tile's carry is exact — the frozen far-left
     carry sits > width-T cells from any real cell) and edge padding on the
-    right (the paper's 'partial tiles on host' become constant ghost regions
-    here).  Kernel output block c holds cells [cW - T, (c+1)W - T) of the
-    padded domain; real cell m lives at ybuf[m + width + T].
+    right, at least T cells and up to a whole number of the kernel's blocks
+    (the paper's 'partial tiles on host' become constant ghost regions
+    here).  Kernel output position p holds cell p - T of the padded
+    domain; real cell m lives at ybuf[m + width + T].
 
     HBM accounting charges the irredundant scheme: each cell is read once
     and written once per pass regardless of T, the carry riding in VMEM
@@ -216,5 +225,7 @@ def jacobi1d_tiled(x: jax.Array, t_steps: int, width: int = 512,
         out = _jacobi1d_tiled_jit(x, t_steps, width, use_pallas)
     if record:
         n = x.shape[0]
-        _record("jacobi1d", m, *jacobi_io_bytes(n), t_steps=t_steps)
+        grid = {} if m == "ref" else {"grid_steps": jacobi_mars.grid_steps(
+            _jacobi_padded_cells(n, t_steps, width), width)}
+        _record("jacobi1d", m, *jacobi_io_bytes(n), t_steps=t_steps, **grid)
     return out

@@ -4,7 +4,8 @@ import pytest
 
 import jax.numpy as jnp
 
-from repro.kernels import ops, ref
+from repro import obs
+from repro.kernels import jacobi_mars, ops, ref
 
 
 @pytest.mark.parametrize("bits", [4, 6, 8, 12, 16])
@@ -49,8 +50,16 @@ def test_kv_quant_sweep(bits, rows, d, dtype):
     assert (np.abs(y_ref - xf).max(axis=1) <= qstep + 1e-5).all()
 
 
+# cells per grid step (2048 rows of 128); the last three cases cross block
+# boundaries: 3 grid steps each, the last one of (8, 512, ...) all padding,
+# that of (16, 1024, ...) mostly padding
+BLOCK = jacobi_mars.BLOCK_BYTES // 4
+
+
 @pytest.mark.parametrize("t_steps,width,n", [
-    (4, 256, 1024), (16, 512, 2048), (63, 128, 1024), (8, 1024, 4096)])
+    (4, 256, 1024), (16, 512, 2048), (63, 128, 1024), (8, 1024, 4096),
+    (8, 512, 2 * BLOCK - 512), (16, 1024, 2 * BLOCK + 5000),
+    (63, 128, 2 * BLOCK + 1000)])
 def test_jacobi_chunked_sweep(t_steps, width, n):
     rng = np.random.default_rng(n)
     x = rng.standard_normal(n).astype(np.float32)
@@ -58,6 +67,26 @@ def test_jacobi_chunked_sweep(t_steps, width, n):
     y_int = np.asarray(ops.jacobi1d_tiled(jnp.asarray(x), t_steps, width=width,
                                           use_pallas="interpret"))
     assert np.abs(y_ref - y_int).max() < 1e-5
+
+
+@pytest.mark.parametrize("n,t_steps,width,steps", [
+    (1024, 63, 128, 1), (2 * BLOCK - 512, 8, 512, 3),
+    (2 * BLOCK - 520, 8, 512, 2), (2 * BLOCK + 5000, 16, 1024, 3),
+    (1 << 29, 8, 512, 2049)])
+def test_jacobi_grid_steps(n, t_steps, width, steps):
+    padded = ops._jacobi_padded_cells(n, t_steps, width)
+    assert padded >= width + n + t_steps
+    assert jacobi_mars.grid_steps(padded, width) == steps
+    assert padded == steps * jacobi_mars.block_rows(padded, width) * 128
+
+
+def test_jacobi_grid_steps_counter():
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(BLOCK + 1),
+                    jnp.float32)
+    with obs.enabled_scope() as (reg, _):
+        ops.jacobi1d_tiled(x, 8, width=512, use_pallas="interpret")
+    assert reg.counter_value("kernels/calls", kernel="jacobi1d",
+                             mode="interpret", t_steps=8, grid_steps=2) == 1
 
 
 def test_ops_ref_fallback_matches_interpret():

@@ -93,6 +93,20 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text(), name
 
 
+def test_jacobi_pass_has_no_relayout(one_chip):
+    """The kernel's (cells/128, 128) view is a bitcast of the 1-D field's
+    layout: the compiled pass is the pad fusion, the kernel and the slice,
+    with no copy or reshape around the kernel."""
+    from repro.kernels import ops
+    x = jax.ShapeDtypeStruct((1 << 26,), f32, sharding=one_chip)
+    text = jax.jit(functools.partial(
+        ops._jacobi1d_tiled_jit, t_steps=8, width=512,
+        use_pallas="pallas")).lower(x).compile().as_text()
+    entry = text[text.index("\nENTRY"):]
+    assert entry.count("tpu_custom_call") == 1
+    assert "copy(" not in entry and "reshape(" not in entry, entry
+
+
 def test_sharded_train_step_compiles_for_v5e_2x2(v5e_chips, monkeypatch):
     """GSPMD cannot partition a Mosaic kernel: under a mesh the flash kernel
     must run per shard.  Small widths; the mesh is the four described chips."""
