@@ -32,7 +32,9 @@ class ModelConfig:
     # --- MoE ---
     n_experts: int = 0
     topk: int = 0
-    capacity_factor: float = 1.25
+    # (first, count) of the experts this share holds and computes (expert
+    # parallelism: the router still scores all n_experts); None holds all
+    expert_share: Optional[Tuple[int, int]] = None
     # --- SSM (mamba2 / hybrid) ---
     ssm_state: int = 0
     ssm_expand: int = 2
@@ -52,6 +54,11 @@ class ModelConfig:
         if self.head_dim:
             return self.head_dim
         return self.d_model // self.n_heads if self.n_heads else 0
+
+    @property
+    def held_experts(self) -> Tuple[int, int]:
+        """(first, count) of the experts held here."""
+        return self.expert_share or (0, self.n_experts)
 
     @property
     def d_inner(self) -> int:

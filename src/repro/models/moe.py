@@ -1,14 +1,23 @@
-"""Top-k MoE block (mixtral / grok): scatter-based token dispatch.
+"""Top-k MoE block (mixtral / grok): dropless, grouped by expert.
 
-Static-shape dropping implementation (GShard/Switch lineage): each expert has
-capacity C = ceil(topk * tokens * capacity_factor / E); tokens route to their
-top-k experts, position-in-expert comes from a cumulative one-hot count, and
-overflow tokens are dropped (scatter mode='drop').  The dispatch buffers
-(E, C, d) are the MoE analogue of MARS blocks: atomic (an expert consumes its
-buffer wholly), irredundant (each routed token copy stored once), contiguous.
+The router scores every token against all ``n_experts`` experts (f32
+softmax over the logits, top-k of the probabilities, the k weights
+renormalized to sum to one, as Mixtral routes).  The layer holds a share
+of the experts, ``ModelConfig.held_experts`` = (first, count): its
+parameters carry those ``count`` experts only, and it computes the part of
+the result that they give.  Routed (token, expert) rows are sorted by
+expert, and each held expert runs its SwiGLU on exactly the rows routed to
+it through a grouped matmul (``jax.lax.ragged_dot``; on a TPU a Mosaic
+kernel that reads each routed expert's weights once and runs a group over
+whole row tiles: at a decode batch, one tile of all the rows).  No token
+is dropped, whatever the routing.  Rows routed to
+an expert this share does not hold contribute nothing here: in an
+expert-parallel deployment another chip's share gives them, and the shares'
+results add up to the whole layer.
 
-Baseline sharding is TP-within-expert (ff dim on 'model'); an
-expert-parallel mesh layout is explored in EXPERIMENTS.md §Perf.
+Device scopes for the benchmark's trace: ``moe_route`` (router, top-k,
+grouping and the weighted combine) and ``moe_experts`` (the grouped
+matmuls).
 """
 from __future__ import annotations
 
@@ -18,27 +27,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.distributed import sharding as shd
 
 F32 = jnp.float32
 
 
 class MoeParams(NamedTuple):
-    router: jax.Array      # (d, E)
-    w_gate: jax.Array      # (E, d, ff)
-    w_up: jax.Array        # (E, d, ff)
-    w_down: jax.Array      # (E, ff, d)
+    router: jax.Array      # (d, n_experts): scores every expert
+    w_gate: jax.Array      # (count, d, ff): the held experts only
+    w_up: jax.Array        # (count, d, ff)
+    w_down: jax.Array      # (count, ff, d)
 
 
 def init_moe(key, cfg: ModelConfig, dtype) -> MoeParams:
     d, ff, E = cfg.d_model, cfg.d_ff, cfg.n_experts
+    _, count = cfg.held_experts
     k1, k2, k3, k4 = jax.random.split(key, 4)
     s = d ** -0.5
     return MoeParams(
         router=(jax.random.normal(k1, (d, E)) * s).astype(dtype),
-        w_gate=(jax.random.normal(k2, (E, d, ff)) * s).astype(dtype),
-        w_up=(jax.random.normal(k3, (E, d, ff)) * s).astype(dtype),
-        w_down=(jax.random.normal(k4, (E, ff, d)) * ff ** -0.5).astype(dtype),
+        w_gate=(jax.random.normal(k2, (count, d, ff)) * s).astype(dtype),
+        w_up=(jax.random.normal(k3, (count, d, ff)) * s).astype(dtype),
+        w_down=(jax.random.normal(k4, (count, ff, d)) * ff ** -0.5
+                ).astype(dtype),
     )
 
 
@@ -51,51 +61,56 @@ def moe_specs() -> MoeParams:
     )
 
 
-def moe_block(x: jax.Array, p: MoeParams, cfg: ModelConfig
-              ) -> Tuple[jax.Array, jax.Array]:
-    """x: (B, S, d) -> (out (B, S, d), load-balance aux loss)."""
+def moe_block(x: jax.Array, p: MoeParams, cfg: ModelConfig,
+              group0: jax.Array | int = 0) -> Tuple[jax.Array, jax.Array]:
+    """x: (B, S, d) -> (this share's part of the output (B, S, d),
+    load-balance aux loss over all experts).
+
+    ``p.router`` is one layer's (d, n_experts).  The expert weights are a
+    table of groups, (G, d, ff) and (G, ff, d), in which this layer's held
+    experts are the ``count`` groups from ``group0``; the table's other
+    groups get no rows.  One layer's parameters are such a table (G =
+    count, ``group0`` 0).  A caller with every layer's experts stacked can
+    hand them over as one table of L * count groups, so that no layer's
+    weights are copied out of the stack (a slice cannot fuse into the
+    grouped-matmul kernel)."""
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.topk
+    first, count = cfg.held_experts
     n = B * S
     xf = x.reshape(n, d)
 
-    gate_logits = (xf @ p.router).astype(F32)             # (n, E)
-    probs = jax.nn.softmax(gate_logits, axis=-1)
-    top_w, top_e = jax.lax.top_k(probs, k)                # (n, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe_route"):
+        probs = jax.nn.softmax(
+            jnp.dot(xf, p.router, preferred_element_type=F32), axis=-1)
+        top_w, top_e = jax.lax.top_k(probs, k)                 # (n, k)
+        top_w = top_w / jnp.sum(top_w, axis=-1, keepdims=True)
+        # aux load-balancing loss (Switch eq. 4/5)
+        frac_tokens = jnp.mean(jax.nn.one_hot(top_e[:, 0], E, dtype=F32),
+                               axis=0)
+        aux = E * jnp.sum(frac_tokens * jnp.mean(probs, axis=0))
+        # rows (token, choice) sorted by held expert; rows for experts not
+        # held here go last, past every group
+        local = top_e.reshape(-1) - first                    # (n*k,)
+        held = (local >= 0) & (local < count)
+        group = jnp.where(held, local, count)
+        order = jnp.argsort(group, stable=True)
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((p.w_gate.shape[0],), jnp.int32),
+            jnp.sum(jax.nn.one_hot(group, count, dtype=jnp.int32), axis=0),
+            (group0,))
+        rows = jnp.take(xf, order // k, axis=0)              # (n*k, d)
 
-    # aux load-balancing loss (Switch eq. 4/5)
-    frac_tokens = jnp.mean(
-        jax.nn.one_hot(top_e[:, 0], E, dtype=F32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
-    aux = E * jnp.sum(frac_tokens * frac_probs)
+    with jax.named_scope("moe_experts"):
+        h = (jax.nn.silu(jax.lax.ragged_dot(rows, p.w_gate, sizes))
+             * jax.lax.ragged_dot(rows, p.w_up, sizes))
+        y = jax.lax.ragged_dot(h, p.w_down, sizes)           # (n*k, d)
 
-    cap = int(cfg.capacity_factor * k * n / E)
-    cap = max(cap, 1)
-
-    e_flat = top_e.reshape(-1)                            # (n*k,)
-    onehot = jax.nn.one_hot(e_flat, E, dtype=jnp.int32)
-    pos = jnp.take_along_axis(
-        jnp.cumsum(onehot, axis=0) - onehot, e_flat[:, None], axis=1)[:, 0]
-    keep = pos < cap
-    pos_c = jnp.where(keep, pos, cap)                     # cap -> dropped
-
-    tok_idx = jnp.repeat(jnp.arange(n), k)
-    x_dup = jnp.take(xf, tok_idx, axis=0)                 # (n*k, d)
-    idx = jnp.stack([e_flat, pos_c], axis=1)              # (n*k, 2)
-    buf = jnp.zeros((E, cap, d), x.dtype)
-    buf = buf.at[idx[:, 0], idx[:, 1]].add(
-        x_dup, mode="drop")                               # (E, C, d)
-    buf = shd.act(buf, "experts", "batch", None)
-
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p.w_gate))
-    h = h * jnp.einsum("ecd,edf->ecf", buf, p.w_up)
-    h = shd.act(h, "experts", "batch", "ff")
-    out_buf = jnp.einsum("ecf,efd->ecd", h, p.w_down)     # (E, C, d)
-
-    gathered = out_buf.at[idx[:, 0], idx[:, 1]].get(
-        mode="fill", fill_value=0)                        # (n*k, d)
-    gathered = jnp.where(keep[:, None], gathered, 0)
-    w = top_w.reshape(-1)[:, None].astype(x.dtype)
-    out = jnp.zeros((n, d), x.dtype).at[tok_idx].add(gathered * w)
-    return out.reshape(B, S, d), aux
+    with jax.named_scope("moe_route"):
+        back = jnp.argsort(order)                            # unsort
+        y = jnp.take(y, back, axis=0).reshape(n, k, d)
+        w = top_w.reshape(n, k, 1)
+        # rows past the groups are left unwritten by the grouped matmul
+        out = jnp.sum(jnp.where(held.reshape(n, k, 1), y.astype(F32) * w,
+                                0.0), axis=1)
+    return out.astype(x.dtype).reshape(B, S, d), aux

@@ -218,9 +218,18 @@ def decode_step(params: DenseParams, state: DecodeState, tokens: jax.Array,
                 cfg: ModelConfig, rc: RunConfig) -> Tuple[jax.Array, DecodeState]:
     """One decode step.  tokens: (B,) -> (logits (B, V), new state)."""
     x = L.embed(tokens[:, None], params.embed)            # (B, 1, d)
+    layers, moe = params.layers, params.layers.moe
+    if moe is not None:
+        # every layer's experts as one table of groups, handed whole to
+        # each layer's grouped matmuls; the layer loop carries the routers
+        count = cfg.held_experts[1]
+        table = [w.reshape((-1,) + w.shape[2:])
+                 for w in (moe.w_gate, moe.w_up, moe.w_down)]
+        layers = layers._replace(moe=moe._replace(w_gate=None, w_up=None,
+                                                  w_down=None))
 
     def scan_fn(x, layer):
-        lp, cache = layer
+        lp, cache, i = layer
         h = L.rmsnorm(x, lp.ln1, cfg.norm_eps)
         new_kv, new_ssm = cache.kv, cache.ssm
         if cfg.family == "hybrid":
@@ -241,13 +250,16 @@ def decode_step(params: DenseParams, state: DecodeState, tokens: jax.Array,
         if lp.ln2 is not None:
             h2 = L.rmsnorm(x, lp.ln2, cfg.norm_eps)
             if cfg.family == "moe":
-                out, _ = M.moe_block(h2, lp.moe, cfg)
+                out, _ = M.moe_block(h2, lp.moe._replace(
+                    w_gate=table[0], w_up=table[1], w_down=table[2]),
+                    cfg, group0=i * count)
                 x = x + out
             else:
                 x = x + L.mlp(h2, lp.mlp, cfg.mlp_act)
         return x, LayerCache(kv=new_kv, ssm=new_ssm)
 
-    x, caches = jax.lax.scan(scan_fn, x, (params.layers, state.caches))
+    index = None if moe is None else jnp.arange(cfg.n_layers)
+    x, caches = jax.lax.scan(scan_fn, x, (layers, state.caches, index))
     lg = L.logits(x, params.embed, cfg)[:, 0]
     return lg, DecodeState(caches=caches, pos=state.pos + 1)
 

@@ -86,8 +86,7 @@ def test_decode_matches_teacher_forcing(arch):
 
 
 def test_moe_decode_matches_with_no_drop_capacity():
-    cfg = dataclasses.replace(base.load_smoke("mixtral-8x7b"),
-                              capacity_factor=8.0)
+    cfg = base.load_smoke("mixtral-8x7b")
     rc = base.RunConfig(seq_len=32, global_batch=2, kind="decode", remat=False,
                         q_block=16, kv_block=16, param_dtype="float32")
     api = model_zoo.get_api(cfg, rc)
@@ -105,8 +104,7 @@ def test_moe_decode_matches_with_no_drop_capacity():
 
 def test_sliding_window_ring_cache_equals_full_cache():
     """SWA ring buffer (long_500k mechanism) == full cache with window mask."""
-    cfg = base.load_smoke("mixtral-8x7b")          # window 64
-    cfg = dataclasses.replace(cfg, capacity_factor=8.0, sliding_window=8)
+    cfg = dataclasses.replace(base.load_smoke("mixtral-8x7b"), sliding_window=8)
     toks = jnp.asarray(np.random.default_rng(3).integers(
         0, cfg.vocab, size=(1, 24), dtype=np.int32))
     outs = {}
