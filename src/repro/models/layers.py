@@ -382,21 +382,26 @@ def _dequant_rows(codes: jax.Array, scale: jax.Array, bits: int) -> jax.Array:
 
 def update_cache(cache: KVCache, k_new: jax.Array, v_new: jax.Array,
                  pos: jax.Array, bits: int) -> KVCache:
-    """Insert (B, 1, KV, D) new kv at per-batch position ``pos`` (B,)."""
+    """Insert (B, 1, KV, D) new kv at per-batch position ``pos`` (B,).
+
+    One indexed scatter per cache array writes every sequence's row: a
+    ``dynamic_update_slice`` per sequence compiles on the TPU to a serial
+    loop over the sequences.  ``pos`` is clamped into the cache, as
+    ``dynamic_update_slice`` clamps its start, so the indices are in bounds.
+    """
+    B, S = cache.k.shape[:2]
+    rows, slot = jnp.arange(B), jnp.clip(pos, 0, S - 1)
+
+    def put(x, new):
+        return x.at[rows, slot].set(new[:, 0].astype(x.dtype),
+                                    mode="promise_in_bounds")
+
     if bits == 16:
-        upd = functools.partial(jax.lax.dynamic_update_slice_in_dim, axis=0)
-        k = jax.vmap(upd)(cache.k, k_new.astype(cache.k.dtype), pos)
-        v = jax.vmap(upd)(cache.v, v_new.astype(cache.v.dtype), pos)
-        return KVCache(k, v, None, None)
+        return KVCache(put(cache.k, k_new), put(cache.v, v_new), None, None)
     kq, ks = _quant_rows(k_new, bits)
     vq, vs = _quant_rows(v_new, bits)
-    upd = functools.partial(jax.lax.dynamic_update_slice_in_dim, axis=0)
-    return KVCache(
-        k=jax.vmap(upd)(cache.k, kq, pos),
-        v=jax.vmap(upd)(cache.v, vq, pos),
-        k_scale=jax.vmap(upd)(cache.k_scale, ks, pos),
-        v_scale=jax.vmap(upd)(cache.v_scale, vs, pos),
-    )
+    return KVCache(put(cache.k, kq), put(cache.v, vq),
+                   put(cache.k_scale, ks), put(cache.v_scale, vs))
 
 
 def decode_attention(x: jax.Array, p: AttnParams, cfg: ModelConfig,
@@ -420,9 +425,9 @@ def decode_attention(x: jax.Array, p: AttnParams, cfg: ModelConfig,
     with jax.named_scope("kv_cache_write"):
         cache = update_cache(cache, k_new, v_new, slot, bits)
 
-    # the dequant + attention region deploys as a fused Pallas kernel on TPU
-    # (kernels/kvpack dequant fused into flash-decode): codes are read from
-    # HBM once, dequantized in VMEM — scoped for the roofline walker.
+    # the dequant + attention region, scoped for the benchmark's device trace
+    # (kv_attn_ms.serve).  It runs in XLA: on the TPU the dequant of the whole
+    # cache is a multiply-convert fusion per step, not a fused kernel.
     # k/v stay in bf16 with f32 MXU accumulation: a whole-cache .astype(F32)
     # gets hoisted out of the layer loop by XLA, doubling cache residency.
     with jax.named_scope("decode_attn_interior"):

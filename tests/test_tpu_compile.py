@@ -168,3 +168,48 @@ def test_flash_split_kv_heads_compiles_for_v5e_1x4(v5e_chips):
         compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
             q, kv, kv).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_kv_cache_write_is_not_a_loop(one_chip):
+    """The decode step at granite-8b widths (2 layers, batch 256, an int8
+    cache of 576): no ``while`` loop carries the ``kv_cache_write`` scope,
+    and a scatter does, so ``kv_write_ms.serve`` has ops to read.  A
+    ``vmap`` of ``dynamic_update_slice`` compiles to one serial loop over
+    the sequences per cache array."""
+    import dataclasses
+    import os
+    import re
+    import sys
+
+    from repro.configs import base
+    from repro.configs.granite_8b import CONFIG
+    from repro.models import model_zoo
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(
+        __file__)), "..", "benchmarks", "tpu"))
+    import devtrace
+
+    batch = 256
+    cfg = dataclasses.replace(CONFIG, n_layers=2, rope_theta=1e7)
+    rc = base.RunConfig(seq_len=576, global_batch=batch, kind="decode",
+                        kv_cache_bits=8)
+    api = model_zoo.get_api(cfg, rc)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+    params = on_chip(jax.eval_shape(api.init, jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: api.init_decode_state(batch)))
+    tokens = jax.ShapeDtypeStruct((batch,), i32, sharding=one_chip)
+    text = jax.jit(api.decode_step).lower(params, state,
+                                          tokens).compile().as_text()
+
+    lines = {m.group(1): line for line in text.splitlines()
+             if (m := re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=", line))}
+    scoped = [lines[n] for n in devtrace.scoped_instructions(
+        text, "kv_cache_write")]
+    assert not [line for line in scoped if " while(" in line], scoped
+    # the scatters are fusions (in place, aliasing the cache) named for the
+    # scatter they hold
+    assert any(re.search(r'op_name="[^"]*kv_cache_write/scatter"', line)
+               for line in scoped), scoped
