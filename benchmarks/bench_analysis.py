@@ -9,14 +9,12 @@ nobody invoked the CLI.
 from repro.analysis import runner
 
 
-def run(smoke: bool = False):
+def run():
     st = runner.selftest()
     if not st["ok"]:
         missed = [k for k, v in st["fired"].items() if not v]
         raise RuntimeError(f"analysis selftest missed: {missed}")
-    # smoke skips the jax kernel-lowering pass (bench_audit already
-    # compiles the same grid in its subprocess); full runs everything
-    report = runner.run_all(with_access=not smoke)
+    report = runner.run_all()
     runner.publish_report(report)
     print(runner.render_report(report))
     if report["n_new"]:

@@ -35,10 +35,10 @@ DEFAULT_OUT = os.path.join(os.path.dirname(__file__), "out")
 
 
 def sections(smoke: bool):
-    from benchmarks import (bench_analysis, bench_audit, bench_ckpt,
-                            bench_codec, bench_collectives, bench_kvcache,
-                            bench_stencil_kernel, fig10_transfer,
-                            fig11_ratio, table1_mars, table2_compile)
+    from benchmarks import (bench_analysis, bench_ckpt, bench_codec,
+                            bench_collectives, bench_stencil_kernel,
+                            fig10_transfer, fig11_ratio, table1_mars,
+                            table2_compile)
 
     # every section runs in smoke mode too (reduced grids) so the
     # regression gate sees kernels/collectives/ckpt series in CI
@@ -52,18 +52,15 @@ def sections(smoke: bool):
          lambda: fig11_ratio.run(smoke=smoke)),
         ("bench_codec", "Beyond-paper: vectorized codec + executor",
          lambda: bench_codec.run(smoke=smoke)),
-        ("bench_kvcache", "Beyond-paper: packed KV cache", bench_kvcache.run),
         ("bench_collectives", "Beyond-paper: compressed collectives",
          lambda: bench_collectives.run(smoke=smoke)),
-        ("bench_audit", "Beyond-paper: HLO-vs-analytic byte audit",
-         lambda: bench_audit.run(smoke=smoke)),
         ("bench_stencil_kernel",
          "Beyond-paper: irredundant stencil kernel",
          lambda: bench_stencil_kernel.run(smoke=smoke)),
         ("bench_ckpt", "Beyond-paper: checkpoint save/restore",
          lambda: bench_ckpt.run(smoke=smoke)),
         ("bench_analysis", "Beyond-paper: static layout/access linter",
-         lambda: bench_analysis.run(smoke=smoke)),
+         bench_analysis.run),
     ]
 
 

@@ -48,7 +48,6 @@ from repro.configs import base  # noqa: E402
 from repro.data.pipeline import SyntheticPipeline, device_batch  # noqa: E402
 from repro.distributed import sharding as shd  # noqa: E402
 from repro.kernels import ops, ref  # noqa: E402
-from repro.launch import roofline  # noqa: E402
 from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import model_zoo  # noqa: E402
@@ -368,9 +367,6 @@ def four_chip_phase(cfg: base.ModelConfig, rc: base.RunConfig,
     comp, hlo_comp = train_steps(
         cfg, dataclasses.replace(rc, grad_compress_bits=8), pods, n)
     print("plain losses:", plain, "8-bit losses:", comp, flush=True)
-    print("collective bytes per device, plain:",
-          roofline.collective_bytes(hlo_plain), "8-bit:",
-          roofline.collective_bytes(hlo_comp), flush=True)
     n_plain, n_comp = u32_collectives(hlo_plain), u32_collectives(hlo_comp)
     check("quantized exchange is in the compiled 8-bit step",
           n_comp > 0 and n_plain == 0,
@@ -400,7 +396,6 @@ def main(argv=None) -> int:
         print(f"chip_smoke: --chips {args.chips} but JAX found "
               f"{len(devices)}", file=sys.stderr)
         return 2
-    roofline.peaks(dev.device_kind)   # a chip without known peaks is an error
     cache = enable_compile_cache()
     print(f"device: {dev.device_kind} x{len(devices)}; compile cache {cache}",
           flush=True)

@@ -3,9 +3,8 @@
 Three pass families, one findings model, one CLI
 (``python -m repro.analysis``):
 
-* ``access`` — compiled-HLO access patterns: redundant entry traffic
-  vs the irredundant byte model (ACC101), non-contiguous innermost
-  access on off-chip residents (ACC102), pack-width alignment (ACC103);
+* ``access`` — pack-width alignment of the bit-plane codec and the
+  ``DATA_TYPES`` container widths (ACC103);
 * ``obs_discipline`` — AST proof that no ``repro.obs`` recording call
   is reachable inside a traced function (OBS201);
 * ``layout_invariants`` — solved layouts over the config zoo are valid

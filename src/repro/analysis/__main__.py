@@ -18,7 +18,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m repro.analysis",
         description="Static layout/access-pattern/obs-discipline linter: "
-                    "proves irredundancy, contiguity and obs discipline "
+                    "checks layouts, pack widths and obs discipline "
                     "before anything runs.")
     ap.add_argument("--root", default=runner.DEFAULT_ROOT,
                     help="source tree for the obs-discipline pass")
@@ -30,9 +30,6 @@ def main(argv=None) -> int:
                     help="suppression baseline file")
     ap.add_argument("--write-baseline", action="store_true",
                     help="record current findings as suppressed and exit 0")
-    ap.add_argument("--no-access", action="store_true",
-                    help="skip the jax-lowering access pass (host-only "
-                         "table checks still run)")
     ap.add_argument("--json", help="also write the report as JSON")
     ap.add_argument("--selftest", action="store_true",
                     help="inject one violation per rule family and verify "
@@ -46,8 +43,7 @@ def main(argv=None) -> int:
         print(f"selftest: {'ok' if st['ok'] else 'FAILED'}")
         return 0 if st["ok"] else 2
 
-    report = runner.run_all(root=args.root, baseline_path=args.baseline,
-                            with_access=not args.no_access)
+    report = runner.run_all(root=args.root, baseline_path=args.baseline)
     print(runner.render_report(report))
     if args.json:
         with open(args.json, "w") as f:

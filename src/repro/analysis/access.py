@@ -1,270 +1,61 @@
-"""Access-pattern pass: redundancy, contiguity, pack alignment — pre-run.
+"""Access-pattern pass: pack alignment of the bit-plane codec — pre-run.
 
-Mirrors the kernel grid of ``launch.audit.kernel_io_audit`` (same shapes,
-same lowering) but asks different questions of the optimized HLO:
-
-* **ACC101 redundant traffic** (error): the compiled ENTRY moves more
-  bytes than the analytic irredundant charge (``ops.*_io_bytes`` — "read
-  every input once, write every output once").  Excess read or write
-  traffic means the lowering re-materializes off-chip data the layout
-  was supposed to make irredundant.
-* **ACC102 non-contiguous innermost access** (warning): a ``gather``,
-  stride>1 innermost ``slice``, innermost-moving ``transpose`` or
-  ``reverse`` applied to ENTRY-parameter-derived data.  Off-chip
-  residents are charged by the AXI burst model in ``core/transfer.py``;
-  breaking the innermost dimension turns one long burst into per-element
-  bursts, and the message quotes the cycle inflation for the shape.
 * **ACC103 misaligned pack width** (error): a pack/unpack case whose bit
   width does not tile the 32-bit plane word (``32 % bits != 0``) or whose
   block does not fill whole plane words (``block % 32 != 0``); plus the
   static ``DATA_TYPES`` table check that every container width equals
   ``packing.padded_width(nbits)``.
 
-The pass needs jax to lower the cases (imports deferred like audit's);
-the rule logic itself is pure text/arith over ``launch.hlo_text`` parses
-so fixtures can exercise it HLO-in, findings-out.
+The pass is pure arithmetic over static tables: it needs no jax.
 """
 from __future__ import annotations
 
 import dataclasses
-import re
-from typing import Callable, Dict, List, Sequence, Set, Tuple
+from typing import List
 
 from repro.core.packing import DATA_TYPES, padded_width
-from repro.core.transfer import TransferModel
-from repro.launch import hlo_text
 
 from .findings import Finding
 
 PASS_NAME = "access-pattern"
 
-#: exact tolerance for byte comparisons (float round-off only)
-BYTES_RTOL = 1e-9
-
-#: ops that permute or scatter their operand's address stream
-_NONCONTIG_OPS = ("gather", "transpose", "reverse", "slice")
-
 
 @dataclasses.dataclass
-class KernelCase:
-    """One lowered kernel + its analytic irredundant byte charge."""
+class PackCase:
+    """One plane pack/unpack: its code width and values per block."""
     name: str
-    hlo: str
-    read_bytes: int
-    write_bytes: int
-    pack_bits: int = 0     # plane-pack bit width (0 = not a packing kernel)
-    pack_block: int = 0
+    bits: int
+    block: int
 
 
-def builtin_cases() -> List[KernelCase]:
-    """The audit kernel grid, lowered (requires jax)."""
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels import ops, ref
-
-    n, block = 256, 32
-    rows, d = 64, 64
-    jn = 4096
-    t_steps = 4
-
-    def lower(fn, *specs):
-        return jax.jit(fn).lower(*specs).compile().as_text()
-
-    s = jax.ShapeDtypeStruct
-    cases: List[KernelCase] = []
-    for bits in (4, 8):
-        r, w = ops.pack_io_bytes(n, block, bits)
-        cases.append(KernelCase(
-            f"kernel/pack/bits{bits}",
-            lower(lambda q, b=bits: ref.pack_ref(q, b),
-                  s((n, block), jnp.int32)),
-            r, w, pack_bits=bits, pack_block=block))
-        r, w = ops.unpack_io_bytes(n, block, bits)
-        cases.append(KernelCase(
-            f"kernel/unpack/bits{bits}",
-            lower(lambda p, b=bits: ref.unpack_ref(p, b, block),
-                  s((n, block // 32 * bits), jnp.uint32)),
-            r, w, pack_bits=bits, pack_block=block))
-        r, w = ops.kv_quant_io_bytes(rows, d, bits)
-        cases.append(KernelCase(
-            f"kernel/kv_quant/bits{bits}",
-            lower(lambda x, b=bits: ref.kv_quant_ref(x, b),
-                  s((rows, d), jnp.float32)),
-            r, w))
-        cd = d if bits == 8 else d // 2
-        r, w = ops.kv_dequant_io_bytes(rows, d, bits)
-        cases.append(KernelCase(
-            f"kernel/kv_dequant/bits{bits}",
-            lower(lambda c, sc, b=bits: ref.kv_dequant_ref(c, sc, b),
-                  s((rows, cd), jnp.int8), s((rows,), jnp.float32)),
-            r, w))
-    r, w = ops.jacobi_io_bytes(jn)
-    cases.append(KernelCase(
-        "kernel/jacobi1d",
-        lower(lambda x: ref.jacobi_chunked_ref(x, t_steps),
-              s((jn,), jnp.float32)),
-        r, w))
-    return cases
-
-
-# ---------------------------------------------------------------------------
-# ACC101: redundant entry traffic
-# ---------------------------------------------------------------------------
-
-def check_redundancy(case: KernelCase) -> List[Finding]:
-    from repro.launch import hlo_walk
-
-    got_r, got_w = hlo_walk.entry_io_bytes(case.hlo)
-    findings = []
-    for kind, got, want in (("read", got_r, case.read_bytes),
-                            ("write", got_w, case.write_bytes)):
-        if got > want * (1 + BYTES_RTOL):
-            findings.append(Finding(
-                rule="ACC101", severity="error",
-                location=case.name,
-                message=(f"compiled ENTRY {kind}s {got} B but the "
-                         f"irredundant model charges {want} B "
-                         f"(+{got - want} B redundant {kind} traffic)"),
-                pass_name=PASS_NAME))
-        elif got < want * (1 - BYTES_RTOL):
-            findings.append(Finding(
-                rule="ACC101", severity="info",
-                location=case.name,
-                message=(f"compiled ENTRY {kind}s {got} B, below the "
-                         f"analytic charge {want} B — model overcharges"),
-                pass_name=PASS_NAME))
-    return findings
-
-
-# ---------------------------------------------------------------------------
-# ACC102: non-contiguous innermost access on off-chip residents
-# ---------------------------------------------------------------------------
-
-def _param_derived(instrs: Sequence[hlo_text.Instr]) -> Set[str]:
-    """Names transitively computed from ENTRY parameters."""
-    derived: Set[str] = {i.name for i in instrs if i.op == "parameter"}
-    changed = True
-    while changed:
-        changed = False
-        for ins in instrs:
-            if ins.name in derived:
-                continue
-            if any(op in derived for op in hlo_text.operand_names(ins.rhs)):
-                derived.add(ins.name)
-                changed = True
-    return derived
-
-
-def _innermost_violation(ins: hlo_text.Instr) -> str:
-    """Reason this instruction breaks innermost contiguity, or ''. """
-    if ins.op == "gather":
-        return "gather indexes off-chip data element-wise"
-    meta = ins.rhs.split(" metadata")[0]
-    if ins.op == "slice":
-        m = re.search(r"slice=\{(.*?)\}", meta)
-        if m:
-            dims = re.findall(r"\[(\d+):(\d+):?(\d*)\]", m.group(1))
-            if dims:
-                stride = int(dims[-1][2] or 1)
-                if stride > 1:
-                    return f"innermost slice stride {stride}"
-        return ""
-    if ins.op in ("transpose", "reverse"):
-        m = re.search(r"dimensions=\{([\d,]*)\}", meta)
-        if not m:
-            return ""
-        dims = [int(d) for d in m.group(1).split(",") if d]
-        _, shapes = hlo_text.shapes_info(ins.result_text)
-        rank = len(shapes[0][1]) if shapes else len(dims)
-        if ins.op == "transpose" and dims and dims[-1] != rank - 1:
-            return f"transpose moves innermost dim (permutation {dims})"
-        if ins.op == "reverse" and dims and (rank - 1) in dims:
-            return "reverse walks the innermost dim backwards"
-    return ""
-
-
-def _burst_quote(ins: hlo_text.Instr, model: TransferModel) -> str:
-    """Cycle inflation of per-element bursts vs one contiguous run."""
-    _, shapes = hlo_text.shapes_info(ins.result_text)
-    if not shapes:
-        return ""
-    dt, dims = shapes[0]
-    elems = 1
-    for d in dims:
-        elems *= d
-    ebits = 8 * hlo_text.DTYPE_BYTES.get(dt, 4)
-    contig = model.transaction_cycles(elems * ebits)
-    scattered = elems * model.transaction_cycles(ebits)
-    return (f"; burst model: {contig} cycles contiguous vs "
-            f"{scattered} scattered ({scattered / max(contig, 1):.1f}x)")
-
-
-def check_contiguity(case: KernelCase,
-                     model: TransferModel = None) -> List[Finding]:
-    model = model or TransferModel()
-    comps = hlo_text.parse_computations(case.hlo)
-    entry = hlo_text.find_entry(case.hlo, comps)
-    instrs = comps.get(entry or "", [])
-    derived = _param_derived(instrs)
-
-    findings = []
-
-    def flag(ins: hlo_text.Instr, reason: str, where: str) -> None:
-        findings.append(Finding(
-            rule="ACC102", severity="warning",
-            location=f"{case.name}/{where}",
-            message=(f"non-contiguous innermost access: {ins.op} %"
-                     f"{ins.name} — {reason}{_burst_quote(ins, model)}"),
-            pass_name=PASS_NAME))
-
-    for ins in instrs:
-        if ins.op in _NONCONTIG_OPS:
-            if not any(op in derived
-                       for op in hlo_text.operand_names(ins.rhs)):
-                continue  # on-chip temporary, not an off-chip stream
-            reason = _innermost_violation(ins)
-            if reason:
-                flag(ins, reason, "entry")
-        elif ins.op == "fusion":
-            m = re.search(r"calls=%?([\w\.\-]+)", ins.rhs)
-            body = comps.get(m.group(1), []) if m else []
-            if not any(op in derived
-                       for op in hlo_text.operand_names(ins.rhs)):
-                continue
-            for bins in body:
-                if bins.op in _NONCONTIG_OPS:
-                    reason = _innermost_violation(bins)
-                    if reason:
-                        flag(bins, reason, m.group(1))
-    return findings
+#: the (bits, block) pairs the pack and unpack kernels are built for
+PACK_CASES = tuple(PackCase(f"kernel/{op}/bits{bits}", bits, 32)
+                   for op in ("pack", "unpack") for bits in (4, 8))
 
 
 # ---------------------------------------------------------------------------
 # ACC103: pack-width alignment
 # ---------------------------------------------------------------------------
 
-def check_pack_alignment(case: KernelCase) -> List[Finding]:
+def check_pack_alignment(case: PackCase) -> List[Finding]:
     findings = []
-    bits, block = case.pack_bits, case.pack_block
-    if bits:
-        if 32 % bits != 0:
-            findings.append(Finding(
-                rule="ACC103", severity="error", location=case.name,
-                message=(f"pack width {bits} does not tile the 32-bit "
-                         "plane word — codes straddle word boundaries"),
-                pass_name=PASS_NAME))
-        if block and block % 32 != 0:
-            findings.append(Finding(
-                rule="ACC103", severity="error", location=case.name,
-                message=(f"block {block} does not fill whole 32-bit plane "
-                         "words (block % 32 != 0)"),
-                pass_name=PASS_NAME))
+    if 32 % case.bits != 0:
+        findings.append(Finding(
+            rule="ACC103", severity="error", location=case.name,
+            message=(f"pack width {case.bits} does not tile the 32-bit "
+                     "plane word — codes straddle word boundaries"),
+            pass_name=PASS_NAME))
+    if case.block % 32 != 0:
+        findings.append(Finding(
+            rule="ACC103", severity="error", location=case.name,
+            message=(f"block {case.block} does not fill whole 32-bit plane "
+                     "words (block % 32 != 0)"),
+            pass_name=PASS_NAME))
     return findings
 
 
 def check_data_types() -> List[Finding]:
-    """Static ``DATA_TYPES`` container-width consistency (no jax)."""
+    """Static ``DATA_TYPES`` container-width consistency."""
     findings = []
     for name, (nbits, width) in sorted(DATA_TYPES.items()):
         want = padded_width(nbits)
@@ -288,12 +79,8 @@ def check_data_types() -> List[Finding]:
 # pass driver
 # ---------------------------------------------------------------------------
 
-def run_pass(cases: Sequence[KernelCase] = None) -> List[Finding]:
-    if cases is None:
-        cases = builtin_cases()
+def run_pass() -> List[Finding]:
     findings: List[Finding] = check_data_types()
-    for case in cases:
-        findings.extend(check_redundancy(case))
-        findings.extend(check_contiguity(case))
+    for case in PACK_CASES:
         findings.extend(check_pack_alignment(case))
     return findings

@@ -1,11 +1,10 @@
 """Multi-pass orchestration for ``repro.analysis``.
 
 ``run_all`` executes the three pass families, applies the suppression
-baseline, and returns a report dict (same JSON-serializable shape idiom
-as ``launch.audit``).  ``publish_report`` emits ``analysis/*`` series so
-``repro.obs.regress`` gates finding counts per PR, and ``selftest``
-injects one violation per rule family and verifies each pass actually
-fires — the analyzer equivalent of audit's ``--perturb-analytic``.
+baseline, and returns a JSON-serializable report dict.  ``publish_report``
+emits ``analysis/*`` series so ``repro.obs.regress`` gates finding counts
+per PR, and ``selftest`` injects one violation per rule family and
+verifies each pass actually fires.
 """
 from __future__ import annotations
 
@@ -20,20 +19,13 @@ DEFAULT_ROOT = "src/repro"
 
 
 def run_all(root: str = DEFAULT_ROOT,
-            baseline_path: Optional[str] = None,
-            with_access: bool = True) -> dict:
-    """Run every pass; split findings against the suppression baseline.
-
-    ``with_access=False`` skips the access-pattern pass (the only one
-    that needs jax to lower kernels) for fast host-only checks.
-    """
-    per_pass: Dict[str, List[Finding]] = {}
-    if with_access:
-        per_pass[access.PASS_NAME] = access.run_pass()
-    else:
-        per_pass[access.PASS_NAME] = access.check_data_types()
-    per_pass[obs_discipline.PASS_NAME] = obs_discipline.analyze_tree(root)
-    per_pass[layout_invariants.PASS_NAME] = layout_invariants.run_pass()
+            baseline_path: Optional[str] = None) -> dict:
+    """Run every pass; split findings against the suppression baseline."""
+    per_pass: Dict[str, List[Finding]] = {
+        access.PASS_NAME: access.run_pass(),
+        obs_discipline.PASS_NAME: obs_discipline.analyze_tree(root),
+        layout_invariants.PASS_NAME: layout_invariants.run_pass(),
+    }
 
     findings = sort_findings(
         [f for fs in per_pass.values() for f in fs])
@@ -95,27 +87,6 @@ def publish_report(report: dict) -> None:
 # Selftest: one injected violation per rule family
 # ---------------------------------------------------------------------------
 
-#: hand-written HLO: ENTRY reads an f32[1024] param and writes it twice
-#: (concat with itself) — 8192 B of writes against a 4096 B analytic charge
-REDUNDANT_HLO = """\
-HloModule redundant
-
-ENTRY %main (p0: f32[1024]) -> f32[2048] {
-  %p0 = f32[1024]{0} parameter(0)
-  ROOT %cat = f32[2048]{0} concatenate(f32[1024]{0} %p0, f32[1024]{0} %p0), dimensions={0}
-}
-"""
-
-#: hand-written HLO: stride-2 innermost slice of an off-chip param
-STRIDED_HLO = """\
-HloModule strided
-
-ENTRY %main (p0: f32[64,64]) -> f32[64,32] {
-  %p0 = f32[64,64]{1,0} parameter(0)
-  ROOT %sl = f32[64,32]{1,0} slice(f32[64,64]{1,0} %p0), slice={[0:64:1], [0:64:2]}
-}
-"""
-
 #: source fixture: obs recording reachable through a jitted helper
 OBS_UNDER_JIT_SRC = """\
 import jax
@@ -142,32 +113,17 @@ def selftest() -> dict:
 
     fired: Dict[str, bool] = {}
 
-    # 1. redundant transfer (ACC101)
-    case = access.KernelCase("selftest/redundant", REDUNDANT_HLO,
-                             read_bytes=4096, write_bytes=4096)
-    fs = access.check_redundancy(case)
-    fired["redundant-transfer"] = any(
-        f.rule == "ACC101" and f.severity == "error" for f in fs)
-
-    # 2. strided innermost access (ACC102)
-    case = access.KernelCase("selftest/strided", STRIDED_HLO,
-                             read_bytes=16384, write_bytes=8192)
-    fs = access.check_contiguity(case)
-    fired["strided-access"] = any(f.rule == "ACC102" for f in fs)
-
-    # 3. misaligned pack width (ACC103): 5 bits does not tile 32
-    case = access.KernelCase("selftest/misaligned", REDUNDANT_HLO,
-                             read_bytes=8192, write_bytes=8192,
-                             pack_bits=5, pack_block=48)
-    fs = access.check_pack_alignment(case)
+    # 1. misaligned pack width (ACC103): 5 bits does not tile 32
+    fs = access.check_pack_alignment(
+        access.PackCase("selftest/misaligned", bits=5, block=48))
     fired["misaligned-pack"] = sum(f.rule == "ACC103" for f in fs) == 2
 
-    # 4. obs recording under jit (OBS201)
+    # 2. obs recording under jit (OBS201)
     nodes = obs_discipline.scan_source(OBS_UNDER_JIT_SRC, "selftest_obs.py")
     fs = obs_discipline.run_pass(nodes)
     fired["obs-under-jit"] = any(f.rule == "OBS201" for f in fs)
 
-    # 5. invalid layout permutation (LAY301): duplicate an index
+    # 3. invalid layout permutation (LAY301): duplicate an index
     a = mars.analyze(stencil.SPECS["jacobi-1d"]((6, 6)))
     good = layout.layout_for_analysis(a)
     bad_order = list(good.order)
@@ -177,7 +133,7 @@ def selftest() -> dict:
     fs = layout_invariants.check_layout("jacobi-1d", (6, 6), a, result=bad)
     fired["invalid-permutation"] = any(f.rule == "LAY301" for f in fs)
 
-    # 6. burst-count lie (LAY302)
+    # 4. burst-count lie (LAY302)
     lied = dataclasses.replace(good, read_bursts=good.read_bursts + 1)
     fs = layout_invariants.check_layout("jacobi-1d", (6, 6), a, result=lied)
     fired["burst-miscount"] = any(f.rule == "LAY302" for f in fs)

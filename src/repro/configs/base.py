@@ -107,26 +107,16 @@ class ModelConfig:
         return L * per_layer + self.vocab * d * 2 + d
 
 
-#: shape_id -> (seq_len, global_batch, kind)
-SHAPES = {
-    "train_4k": (4_096, 256, "train"),
-    "prefill_32k": (32_768, 32, "prefill"),
-    "decode_32k": (32_768, 128, "decode"),
-    "long_500k": (524_288, 1, "decode"),
-}
-
-
 @dataclasses.dataclass(frozen=True)
 class RunConfig:
-    """Execution configuration for one (arch x shape x mesh) cell."""
+    """How one model runs: sequence and batch, sharding, numerics, blocking,
+    the paper's packing options and the optimizer."""
     seq_len: int
     global_batch: int
     kind: str                      # train | prefill | decode
     # parallelism
     fsdp: bool = True              # shard params/opt over the data axis
     seq_shard: bool = True         # shard activations' seq dim over 'model'
-    pipeline_stages: int = 1       # >1: GPipe over the pod axis
-    microbatches: int = 1
     # numerics / memory
     param_dtype: str = "bfloat16"
     opt_dtype: str = "float32"     # bfloat16 for the largest archs
@@ -137,9 +127,6 @@ class RunConfig:
     # attention blocking
     q_block: int = 512
     kv_block: int = 1024
-    # §Perf: hand-scheduled reduce-scatter TP out-projections (shard_map)
-    # instead of SPMD-chosen all-reduce+all-gather pairs
-    tp_scatter: bool = False
     # vocab-dim sharding of embed/unembed
     shard_vocab: bool = True
     # paper-technique features
@@ -170,14 +157,3 @@ def load_arch(arch_id: str) -> ModelConfig:
 def load_smoke(arch_id: str) -> ModelConfig:
     mod = importlib.import_module(f"repro.configs.{arch_id.replace('-', '_').replace('.', '_')}")
     return mod.smoke()
-
-
-def run_config_for(shape_id: str, cfg: ModelConfig, **overrides) -> RunConfig:
-    seq, batch, kind = SHAPES[shape_id]
-    big = cfg.param_count() > 50e9
-    defaults = dict(
-        seq_len=seq, global_batch=batch, kind=kind,
-        opt_dtype="bfloat16" if big else "float32",
-    )
-    defaults.update(overrides)
-    return RunConfig(**defaults)

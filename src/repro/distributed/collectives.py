@@ -20,9 +20,7 @@ lossless codec and its rationale are documented in DESIGN.md §2.
 
 The train step realizes the exchange in pure auto-GSPMD (a vmap over a
 pod-sharded leading axis — this XLA's SPMD partitioner aborts on while ops
-inside manual subgroups, see train/step.py); the equivalent manual-'pod'
-``shard_map`` spelling is compiled and byte-audited against
-``ExchangeStats`` by ``repro.launch.audit``.
+inside manual subgroups, see train/step.py).
 """
 from __future__ import annotations
 

@@ -15,7 +15,7 @@ to mesh axes for the active mesh and run config:
 
 A rule only applies when the dimension size divides the mesh axis size
 (whisper's 6 heads, hymba's 32001 vocab etc. fall back to replication —
-uneven shardings would silently pad and skew the roofline accounting).
+uneven shardings would silently pad).
 
 No global state is touched by importing this module; the launcher installs a
 context via ``use_rules`` / ``set_rules``.
@@ -28,7 +28,6 @@ import threading
 from typing import Optional, Sequence, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -112,68 +111,9 @@ def _is_logical_leaf(x) -> bool:
     return isinstance(x, tuple) and all(isinstance(e, (str, type(None))) for e in x)
 
 
-def spec_tree(logicals, shapes):
-    """Resolve a pytree of logical tuples to PartitionSpecs (for in_shardings).
-
-    ``logicals`` leaves are tuples of logical axis names (or None); ``shapes``
-    is a matching pytree of arrays / ShapeDtypeStructs.
-    """
-    r = get_rules()
-    if r is None:
-        return jax.tree.map(lambda _: P(), logicals, is_leaf=_is_logical_leaf)
-    return jax.tree.map(
-        lambda log, shp: r.spec(shp.shape if hasattr(shp, "shape") else shp, log),
-        logicals, shapes, is_leaf=_is_logical_leaf)
-
-
 def named_sharding(spec: P) -> Optional[NamedSharding]:
     r = get_rules()
     if r is None or r.mesh is None:
         return None
     return NamedSharding(r.mesh, spec)
 
-
-def tp_out_proj(h: jax.Array, w: jax.Array) -> Optional[jax.Array]:
-    """Hand-scheduled tensor-parallel out-projection (§Perf iteration 1).
-
-    ``h``: (B, S, F) activation with F (heads*hd or ff) sharded on 'model';
-    ``w``: (F, d).  The contraction over the sharded F dim needs a cross-
-    'model' reduction; left to GSPMD (on this backend) it materializes a
-    full (B, S, d) f32 all-reduce *plus* an all-gather per layer.  Here the
-    schedule is pinned manually: local partial matmul, then one bf16
-    ``psum_scatter`` onto the seq dim (matching the seq-sharded residual
-    stream) — 1/(2*tp) the bytes in one collective instead of two.
-
-    Returns None when inapplicable (no mesh / tp=1 / indivisible dims) —
-    caller falls back to the plain matmul.
-    """
-    r = get_rules()
-    if r is None or r.mesh is None or "model" in r.exclude:
-        return None
-    tp = r.axis_size("model")
-    if h.ndim != 3 or tp <= 1:
-        return None
-    B, S, F = h.shape
-    if F % tp or w.shape[0] != F:
-        return None
-    scatter = (r.seq_shard and S % tp == 0 and S >= tp)
-    mesh = r.mesh
-
-    def body(hl, wl):
-        # f32 accumulate/scatter: XLA:CPU's AllReducePromotion pass aborts
-        # on bf16 reduce-scatter (TPU deployment would use bf16 wire, halving
-        # these bytes again — noted in EXPERIMENTS.md §Perf)
-        partial = jnp.dot(hl, wl, preferred_element_type=jnp.float32)
-        if scatter:
-            out = jax.lax.psum_scatter(partial, "model",
-                                       scatter_dimension=1, tiled=True)
-        else:
-            out = jax.lax.psum(partial, "model")
-        return out.astype(hl.dtype)
-
-    out_spec = P(None, "model", None) if scatter else P(None, None, None)
-    return jax.shard_map(
-        body, mesh=mesh, axis_names=frozenset({"model"}),
-        in_specs=(P(None, None, "model"), P("model", None)),
-        out_specs=out_spec, check_vma=False,
-    )(h, w)

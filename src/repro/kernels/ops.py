@@ -34,9 +34,7 @@ BEAT_BYTES = 32
 
 # ---------------------------------------------------------------------------
 # Analytic I/O models (read every input once, write every output once) —
-# shared by the ``_record`` instrumentation below and by
-# ``repro.launch.audit``, which cross-checks them against the entry
-# parameter/result bytes of the compiled HLO.
+# what the ``_record`` instrumentation below counts.
 # ---------------------------------------------------------------------------
 
 def pack_io_bytes(n: int, block: int, bits: int):

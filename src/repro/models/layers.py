@@ -194,9 +194,8 @@ def blockwise_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
     # checkpoint each q block: backward recomputes the kv scan instead of
     # storing per-kv-step residuals (flash-attention backward memory shape).
-    # The named scope lets the roofline walker attribute this region's HBM
-    # traffic: on TPU it runs as the Pallas flash kernel (VMEM-resident
-    # blocks), so its interior traffic collapses to the q/k/v/o I/O.
+    # The named scope names this region in the compiled HLO and in device
+    # traces (on TPU the Pallas flash kernel takes its place).
     with jax.named_scope("flash_attn_interior"):
         outs = jax.lax.map(jax.checkpoint(one_q_block),
                            jnp.arange(nq))                 # (nq,B,Bq,KV,G,D)
@@ -260,7 +259,7 @@ def _flash_per_shard(q, k, v, **kw):
 def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
               q_block: int, kv_block: int,
               window_override: Optional[int] = None,
-              causal: bool = True, tp_scatter: bool = False) -> jax.Array:
+              causal: bool = True) -> jax.Array:
     """Full training/prefill self-attention with output projection.
 
     On TPU the inner loops run as the Pallas flash kernel (VMEM-resident
@@ -295,13 +294,7 @@ def attention(x: jax.Array, p: AttnParams, cfg: ModelConfig, pos: jax.Array,
         o = blockwise_attention(q, k, v, causal=causal, window=window,
                                 q_block=qb, kv_block=kb)
     o = shd.act(o, "batch", None, "heads", None)
-    of = o.reshape(B, S, -1)
-    if tp_scatter:
-        out = shd.tp_out_proj(of, p.wo)
-        if out is not None:
-            return checkpoint_name(shd.act(out, "batch", "seq", None),
-                                   "proj_out")
-    out = of @ p.wo
+    out = o.reshape(B, S, -1) @ p.wo
     return checkpoint_name(shd.act(out, "batch", "seq", None), "proj_out")
 
 
@@ -489,17 +482,12 @@ def mlp_specs(act: str) -> MlpParams:
     )
 
 
-def mlp(x: jax.Array, p: MlpParams, act: str,
-        tp_scatter: bool = False) -> jax.Array:
+def mlp(x: jax.Array, p: MlpParams, act: str) -> jax.Array:
     if act == "swiglu":
         h = jax.nn.silu(x @ p.w_gate) * (x @ p.w_up)
     else:
         h = jax.nn.gelu(x @ p.w_up)
     h = shd.act(h, "batch", None, "ff")
-    if tp_scatter:
-        out = shd.tp_out_proj(h, p.w_down)
-        if out is not None:
-            return checkpoint_name(out, "proj_out")
     return checkpoint_name(h @ p.w_down, "proj_out")
 
 

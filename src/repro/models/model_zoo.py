@@ -1,7 +1,7 @@
 """Unified model API: config -> init / loss / prefill / decode / input specs.
 
 ``input_specs`` returns ShapeDtypeStructs (never allocates) for every model
-input of a given (arch, shape) cell — the dry-run contract.  Modality
+input of a given model and run config.  Modality
 frontends are stubs per the assignment: whisper receives precomputed frame
 embeddings, internvl receives precomputed patch embeddings.
 """
@@ -59,11 +59,11 @@ def get_api(cfg: ModelConfig, rc: RunConfig) -> ModelApi:
 
 
 # ---------------------------------------------------------------------------
-# Input specs (ShapeDtypeStruct stand-ins, dry-run contract)
+# Input specs (ShapeDtypeStruct stand-ins)
 # ---------------------------------------------------------------------------
 
 def input_specs(cfg: ModelConfig, rc: RunConfig) -> Dict[str, Any]:
-    """Abstract inputs for the cell's step function.
+    """Abstract inputs for the step function.
 
     train / prefill: token batch (+ stub modality embeddings);
     decode: one token per sequence (the KV cache / state is part of the
@@ -93,16 +93,4 @@ def input_specs(cfg: ModelConfig, rc: RunConfig) -> Dict[str, Any]:
         out["labels"] = jax.ShapeDtypeStruct((B, S - nv), i32)
         out["vis_embeds"] = jax.ShapeDtypeStruct((B, nv, cfg.d_model),
                                                  rc.jdtype)
-    return out
-
-
-def batch_logical_specs(cfg: ModelConfig, rc: RunConfig) -> Dict[str, Any]:
-    """Logical sharding names for the batch dict."""
-    if rc.kind == "decode":
-        return {"tokens": ("batch",)}
-    out = {"tokens": ("batch", "seq"), "labels": ("batch", "seq")}
-    if cfg.family == "encdec":
-        out["frames"] = ("batch", None, None)
-    if cfg.family == "vlm":
-        out["vis_embeds"] = ("batch", None, None)
     return out

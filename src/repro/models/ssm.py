@@ -134,8 +134,7 @@ def ssd_forward(params: SsmParams, x: jax.Array, cfg: ModelConfig
         return h_new, (y_intra + y_inter).astype(adt)
 
     init = jnp.zeros((B, H, N, P), F32)
-    # scoped for the roofline walker: the chunk-local dual-form tensors are
-    # VMEM-resident in the TPU kernelized deployment (see hlo_walk)
+    # scoped so that the compiled HLO and device traces name the chunk scan
     with jax.named_scope("ssd_interior"):
         _, y_c = jax.lax.scan(chunk_step, init, (da_c, b_c, c_c, xdt_c))
     y = jnp.moveaxis(y_c, 0, 1).reshape(B, S, H, P)               # (B,S,H,P)

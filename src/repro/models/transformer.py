@@ -102,8 +102,7 @@ def _layer_fwd(cfg: ModelConfig, rc: RunConfig, x, pos, lp: LayerParams):
     aux = jnp.zeros((), jnp.float32)
     h = L.rmsnorm(x, lp.ln1, cfg.norm_eps)
     if cfg.family == "hybrid":
-        a = L.attention(h, lp.attn, cfg, pos, rc.q_block, rc.kv_block,
-                        tp_scatter=rc.tp_scatter)
+        a = L.attention(h, lp.attn, cfg, pos, rc.q_block, rc.kv_block)
         s = S.ssd_forward(lp.ssm, h, cfg)
         mix = 0.5 * (L.rmsnorm(a, lp.ln_attn_out, cfg.norm_eps)
                      + L.rmsnorm(s, lp.ln_ssm_out, cfg.norm_eps))
@@ -111,15 +110,14 @@ def _layer_fwd(cfg: ModelConfig, rc: RunConfig, x, pos, lp: LayerParams):
     elif cfg.family == "ssm":
         x = x + S.ssd_forward(lp.ssm, h, cfg)
     else:
-        x = x + L.attention(h, lp.attn, cfg, pos, rc.q_block, rc.kv_block,
-                            tp_scatter=rc.tp_scatter)
+        x = x + L.attention(h, lp.attn, cfg, pos, rc.q_block, rc.kv_block)
     if lp.ln2 is not None:
         h2 = L.rmsnorm(x, lp.ln2, cfg.norm_eps)
         if cfg.family == "moe":
             out, aux = M.moe_block(h2, lp.moe, cfg)
             x = x + out
         else:
-            x = x + L.mlp(h2, lp.mlp, cfg.mlp_act, tp_scatter=rc.tp_scatter)
+            x = x + L.mlp(h2, lp.mlp, cfg.mlp_act)
     return shd.act(x, "batch", "seq", None), aux
 
 

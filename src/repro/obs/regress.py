@@ -72,9 +72,6 @@ GATES: List[Tuple[str, str, str]] = [
     ("kernels/hbm_bytes", "lower", EXACT),
     ("kernels/beats", "lower", EXACT),
     ("collectives/wire_bytes", "lower", EXACT),
-    ("audit/divergences", "lower", EXACT),
-    ("audit/hlo_bytes", "lower", EXACT),
-    ("audit/analytic_bytes", "lower", EXACT),
     ("ckpt/bytes_written", "lower", EXACT),
     ("ckpt/bytes_read", "lower", EXACT),
     ("ckpt/save_ms", "lower", WALL),
@@ -205,7 +202,7 @@ def _fmt(v: Optional[float]) -> str:
 
 def render_table(deltas: List[Delta], verbose: bool = False) -> str:
     """Markdown delta table; quiet mode hides untracked/unchanged rows."""
-    from repro.launch.report import md_table
+    from .report import md_table
     rows = []
     for d in deltas:
         if not verbose and d.status in ("untracked", "ok") and not d.note:

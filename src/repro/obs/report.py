@@ -14,9 +14,6 @@ Sections a run did not exercise render as ``n/a`` placeholders rather than
 raising — a smoke run without the beyond-paper benches must still report.
 ``--format=json`` emits the :func:`repro.obs.regress.flatten_series` view
 instead, so the regression gate and humans read the same numbers.
-
-Formatting reuses the markdown-table and duration helpers from
-``repro.launch.report`` so EXPERIMENTS.md-style docs stay consistent.
 """
 from __future__ import annotations
 
@@ -25,13 +22,29 @@ import json
 from collections import defaultdict
 from typing import Dict, Tuple
 
-from repro.launch.report import fmt_s, md_table
-
 from repro.core.transfer import MODES as TRANSFER_PATTERNS
 
 from .metrics import parse_series_key
 from .regress import flatten_series
 from .sink import read_summary
+
+
+def fmt_s(x):
+    """Human seconds: ``1.23s`` / ``4.5ms`` / ``-`` for missing."""
+    if x is None:
+        return "-"
+    if x >= 1:
+        return f"{x:.2f}s"
+    return f"{x * 1e3:.1f}ms"
+
+
+def md_table(header, rows):
+    """Markdown table from a header tuple + row tuples (all stringified)."""
+    lines = ["| " + " | ".join(str(h) for h in header) + " |",
+             "|" + "---|" * len(header)]
+    lines.extend("| " + " | ".join(str(c) for c in row) + " |"
+                 for row in rows)
+    return "\n".join(lines)
 
 
 def _fmt_val(v) -> str:

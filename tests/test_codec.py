@@ -1,4 +1,6 @@
 """Compression codecs: faithful §2.5 stream + TPU block codec."""
+import re
+
 import numpy as np
 import pytest
 
@@ -6,11 +8,15 @@ hypothesis = pytest.importorskip(
     "hypothesis", reason="property tests need the hypothesis package")
 from hypothesis import given, settings, strategies as st  # noqa: E402
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import blockcodec as bc
 from repro.core import compression as comp
 from repro.core import packing
+from repro.distributed import collectives as C
+from repro.kernels import ref
+from repro.models import layers
 
 
 @settings(max_examples=30, deadline=None)
@@ -103,3 +109,60 @@ def test_varwidth_encoder_adapts():
     br, wr = bc.encode_varwidth(rough, 256)
     assert bs < br
     assert ws.max() <= wr.max()
+
+
+# --- compiled access pattern of the packers ---------------------------------
+
+def _pack_case(name, bits):
+    """(fn, input specs) of one quantize/pack function at a small shape."""
+    spec = jax.ShapeDtypeStruct
+    rows, d, n, block = 64, 64, 256, 32
+    cd = d if bits == 8 else d // 2
+    return {
+        "layers._quant_rows": (lambda x: layers._quant_rows(x, bits),
+                               [spec((4, 16, d), jnp.float32)]),
+        "layers._dequant_rows": (
+            lambda c, s: layers._dequant_rows(c, s, bits),
+            [spec((4, 16, cd), jnp.int8), spec((4, 16, 1), jnp.float32)]),
+        "ref.kv_quant_ref": (lambda x: ref.kv_quant_ref(x, bits),
+                             [spec((rows, d), jnp.float32)]),
+        "ref.kv_dequant_ref": (
+            lambda c, s: ref.kv_dequant_ref(c, s, bits),
+            [spec((rows, cd), jnp.int8), spec((rows, 1), jnp.float32)]),
+        "ref.pack_ref": (lambda q: ref.pack_ref(q, bits),
+                         [spec((n, block), jnp.int32)]),
+        "ref.unpack_ref": (lambda p: ref.unpack_ref(p, bits, block),
+                           [spec((n, block // 32 * bits), jnp.uint32)]),
+        "collectives._quant_lastdim": (
+            lambda x: C._quant_lastdim(x, bits),
+            [spec((8, 128), jnp.float32)]),
+        "collectives._dequant_lastdim": (
+            lambda p, s: C._dequant_lastdim(p, s, bits, (8, 128)),
+            [spec((8, 4, bits), jnp.uint32), spec((8, 4), jnp.float32)]),
+    }[name]
+
+
+_PACKERS = ("layers._quant_rows", "layers._dequant_rows",
+            "ref.kv_quant_ref", "ref.kv_dequant_ref", "ref.pack_ref",
+            "ref.unpack_ref", "collectives._quant_lastdim",
+            "collectives._dequant_lastdim")
+
+
+def _innermost_stride(slice_attr):
+    """Stride of the last dimension of an HLO ``slice={[a:b:s], ...}``."""
+    dims = re.findall(r"\[\d+:\d+(?::(\d+))?\]", slice_attr)
+    return int(dims[-1] or 1) if dims else 1
+
+
+@pytest.mark.parametrize("bits", [4, 8])
+@pytest.mark.parametrize("name", _PACKERS)
+def test_pack_lowers_without_gather(name, bits):
+    """Every quantize/pack path reads and writes whole contiguous rows: the
+    compiled program has no gather and no slice striding its innermost
+    dimension (a ``q[..., 0::2]`` nibble pack compiles to gathers)."""
+    fn, specs = _pack_case(name, bits)
+    hlo = jax.jit(fn).lower(*specs).compile().as_text()
+    gathers = re.findall(r"(?<![\w-])gather\(", hlo)
+    strided = [m for m in re.findall(r"slice=\{([^}]*)\}", hlo)
+               if _innermost_stride(m) > 1]
+    assert not gathers and not strided, (len(gathers), strided)

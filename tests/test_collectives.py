@@ -1,11 +1,11 @@
-"""Compressed-exchange codec pieces + cross-pod HLO attribution."""
+"""Compressed-exchange codec pieces and their wire accounting."""
 import numpy as np
+import pytest
 
 import jax
 import jax.numpy as jnp
 
 from repro.distributed import collectives as C
-from repro.launch import hlo_walk
 
 
 def test_quant_lastdim_roundtrip_error_bound():
@@ -55,13 +55,19 @@ def test_error_feedback_converges_unbiased():
     assert err < one_shot / 3, (err, one_shot)
 
 
-def test_xpod_attribution_parsing():
-    assert hlo_walk._crosses_pod(
-        "x, replica_groups=[256,2]<=[2,256]T(1,0), etc") is True
-    assert hlo_walk._crosses_pod(
-        "x, replica_groups=[32,16]<=[512], etc") is False
-    assert hlo_walk._crosses_pod("x, replica_groups={{0,256},{1,257}}") is True
-    assert hlo_walk._crosses_pod("x, replica_groups={{0,16},{1,17}}") is False
-    assert hlo_walk._crosses_pod("x, source_target_pairs={{0,256},{1,257}}") \
-        is True
-    assert hlo_walk._crosses_pod("no groups here") is None
+@pytest.mark.parametrize("bits", [4, 8])
+def test_exchange_stats_match_codec_arrays(bits):
+    """``exchange_stats``' wire bytes are the bytes of the planes and scales
+    the codec really returns for compressible leaves, plus raw leaves."""
+    tree = {"w": jnp.zeros((64, 128), jnp.float32),
+            "norm": jnp.zeros((10,), jnp.float32)}
+    want = 0
+    for g in jax.tree.leaves(tree):
+        if C.compressible(g):
+            out = jax.eval_shape(lambda x: C._quant_lastdim(x, bits), g)
+            want += sum(a.size * a.dtype.itemsize for a in out)
+        else:
+            want += g.size * g.dtype.itemsize
+    st = C.exchange_stats(tree, bits)
+    assert (st.compressed_leaves, st.raw_leaves) == (1, 1)
+    assert st.wire_bytes == want
